@@ -22,6 +22,8 @@ def main(argv=None) -> int:
                          "fleet,kernels,epoch,roofline")
     args = ap.parse_args(argv)
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     def want(name):
         return only is None or name in only

@@ -15,6 +15,7 @@ import numpy as np
 from repro.api import (CodedFL, Session, TrainData, UncodedFL, coding_gain,
                        convergence_time)
 from repro.core.redundancy import solve_redundancy
+from repro.launch.compile_cache import use_compile_cache
 from repro.sim.network import paper_fleet
 
 N, ELL, D = 24, 300, 500
@@ -56,6 +57,7 @@ def main(epochs: int = 600):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=600,
                     help="training epochs (30 for a CI smoke run)")

@@ -53,7 +53,6 @@ from typing import (TYPE_CHECKING, Any, Callable, Dict, Hashable, List,
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import aggregation
@@ -219,18 +218,23 @@ def _build_engine(strategy: Strategy, state: Any, data: TrainData,
             def step(beta, arr_t):
                 return epoch_step(beta, dev, lr, beta_true, arr_t)
 
-            beta_f, trace = jax.lax.scan(step, beta0, arr)
+            # the carry varies over `lanes` once a step has run, so the
+            # initial carry must too
+            beta_f, trace = jax.lax.scan(
+                step, jax.lax.pcast(beta0, "lanes", to="varying"), arr)
             nmse0 = aggregation.nmse(beta0, beta_true)
             return jnp.concatenate([nmse0[None], trace]), beta_f
 
         return jax.lax.map(lane, lane_args)
 
     replicated = jax.tree.map(lambda leaf: P(), shared)
-    fn = shard_map(lanes, mesh=mesh,
-                   in_specs=(replicated,) + tuple(
-                       lane_specs(a) for a in args),
-                   out_specs=(P("lanes"), P("lanes")))
-    return jax.jit(fn)
+    fn = jax.shard_map(lanes, mesh=mesh,
+                       in_specs=(replicated,) + tuple(
+                           lane_specs(a) for a in args),
+                       out_specs=(P("lanes"), P("lanes")))
+    # compiled ahead of the call for this bucket's exact shapes, so the
+    # engine's program text (`as_text()`) can be inspected
+    return jax.jit(fn).lower(shared, *args).compile()
 
 
 def _execute_lanes(entries: Sequence[tuple],

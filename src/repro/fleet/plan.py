@@ -39,7 +39,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.delay_model import total_cdf
@@ -85,6 +84,10 @@ def _solve_fleet_grid(a, mu, tau, p, caps, srv_a, srv_mu, srv_w, srv_cap,
 
     s_ok = ell_s <= srv_cap                                      # (Ls,)
 
+    def varying(x):
+        """Loop carries that accumulate per-shard values start varying."""
+        return jax.lax.pcast(x, "shards", to="varying")
+
     def _shifted_exp_cdf(gamma_, s_):
         return jnp.where(
             s_ > 0.0,
@@ -126,7 +129,8 @@ def _solve_fleet_grid(a, mu, tau, p, caps, srv_a, srv_mu, srv_w, srv_cap,
                     return acc + _shifted_exp_cdf(gamma[None], s)
                 cdf = jax.lax.fori_loop(
                     0, edge_chunks, add_q,
-                    jnp.zeros(t_res.shape + (ell_e.shape[0],), dtype=dtype))
+                    varying(jnp.zeros(t_res.shape + (ell_e.shape[0],),
+                                      dtype=dtype)))
                 cdf = cdf / edge_chunks
             return jnp.where(ell_e > 0.0, cdf,
                              (t_res[..., None] >= 0.0).astype(dtype))
@@ -137,8 +141,8 @@ def _solve_fleet_grid(a, mu, tau, p, caps, srv_a, srv_mu, srv_w, srv_cap,
 
         mix = jax.lax.fori_loop(
             0, n_k, add_k,
-            jnp.zeros(t.shape + (a_c.shape[0], ell_e.shape[0]),
-                      dtype=dtype))
+            varying(jnp.zeros(t.shape + (a_c.shape[0], ell_e.shape[0]),
+                              dtype=dtype)))
         mix = jnp.where(
             jnp.logical_and(mix >= pmf_total[None, :, None],
                             snap_ok[None, :, None]),
@@ -162,7 +166,8 @@ def _solve_fleet_grid(a, mu, tau, p, caps, srv_a, srv_mu, srv_w, srv_cap,
             def step(acc, prm_c):
                 ev = chunk_returns(t, tuple(prm_c))     # (T', chunk, L)
                 return acc + ev.max(axis=-1).sum(axis=-1), None
-            out, _ = jax.lax.scan(step, jnp.zeros_like(t), prm_stack)
+            out, _ = jax.lax.scan(step, varying(jnp.zeros_like(t)),
+                                  prm_stack)
             return out
 
         def best_agg(t):
@@ -227,11 +232,10 @@ def _solve_fleet_grid(a, mu, tau, p, caps, srv_a, srv_mu, srv_w, srv_cap,
         return (loads_l.reshape(-1), t_star, s_load, agg, feasible)
 
     spec_n = P("shards")
-    fn = shard_map(
+    fn = jax.shard_map(
         solve, mesh=mesh,
         in_specs=(spec_n,) * 5,
-        out_specs=(spec_n, P(), P(), P(), P()),
-        check_rep=False)
+        out_specs=(spec_n, P(), P(), P(), P()))
     return fn(a, mu, tau, p, caps)
 
 
@@ -269,7 +273,7 @@ def solve_fleet(request: PlanRequest, eps_rel: float = 1e-3,
     frac = np.arange(1, grid_points + 1, dtype=np.float64) / grid_points
     t_hi0 = req.t_hi if req.t_hi is not None else req.default_t_hi()
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = _solve_fleet_grid(
             a, mu, tau, p, caps,
             np.float64(req.server.a[0]), np.float64(req.server.mu[0]),

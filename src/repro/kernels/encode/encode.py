@@ -39,6 +39,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK = (128, 128, 128)  # (bc, bd, bl)
+# f32 passes on the MXU: at the default precision the chip rounds the
+# operands to bf16, and parity would drift from the host path by ~1e-2
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _kernel(g_ref, w_ref, x_ref, out_ref):
@@ -52,7 +55,7 @@ def _kernel(g_ref, w_ref, x_ref, out_ref):
     w = w_ref[...]                       # (1, bl)
     x = x_ref[...]                       # (bl, bd)
     xw = x * w[0][:, None].astype(x.dtype)   # fused diagonal scaling
-    out_ref[...] += jax.lax.dot(g, xw,
+    out_ref[...] += jax.lax.dot(g, xw, precision=_F32,
                                 preferred_element_type=jnp.float32
                                 ).astype(out_ref.dtype)
 
@@ -125,21 +128,16 @@ def _threefry2x32(k0, k1, x0, x1):
     return x0, x1
 
 
-def _threefry_bits_at(k0, k1, idx: jax.Array, size: int) -> jax.Array:
-    """`jax.random.bits(key, (size,))`'s uint32 stream at flat positions
-    `idx` — the split-half counter pairing of `threefry_2x32(key,
-    iota(size))` evaluated pointwise, so a tile of the stream costs one
-    hash per element instead of materializing all `size` counters."""
-    half = (size + 1) // 2
-    hi_half = idx >= half
-    j = jnp.where(hi_half, idx - half, idx)
-    cnt1 = j + half
-    if size % 2:  # odd sizes pair the last low counter with the zero pad
-        cnt1 = jnp.where(cnt1 == size, 0, cnt1)
+def _threefry_bits_at(k0, k1, idx: jax.Array) -> jax.Array:
+    """`jax.random.bits(key, shape)`'s uint32 stream at flat positions
+    `idx` — the partitionable layout: each value hashes its own 64-bit
+    counter pair (hi, lo) = (0, idx) and XORs the two output words, so a
+    tile of the stream costs one hash per element and depends on nothing
+    but its flat position (streams stay below 2**32 values)."""
     out0, out1 = _threefry2x32(jnp.uint32(k0), jnp.uint32(k1),
-                               j.astype(jnp.uint32),
-                               cnt1.astype(jnp.uint32))
-    return jnp.where(hi_half, out1, out0)
+                               jnp.zeros(idx.shape, jnp.uint32),
+                               idx.astype(jnp.uint32))
+    return out0 ^ out1
 
 
 def _bits_to_generator(bits: jax.Array, kind: str) -> jax.Array:
@@ -170,11 +168,11 @@ def generator_values(key: jax.Array, c: int, ell: int,
     produce — bit-identical to `core.encoding.generator_matrix(key, ...)`
     (enforced in tests/test_kernels.py)."""
     idx = jnp.arange(c * ell, dtype=jnp.int32).reshape(c, ell)
-    bits = _threefry_bits_at(key[0], key[1], idx, c * ell)
+    bits = _threefry_bits_at(key[0], key[1], idx)
     return _bits_to_generator(bits, kind)
 
 
-def _make_prng_kernel(c: int, ell: int, kind: str, block):
+def _make_prng_kernel(ell: int, kind: str, block):
     bc, _, bl = block
 
     def kernel(key_ref, w_ref, x_ref, out_ref):
@@ -192,13 +190,13 @@ def _make_prng_kernel(c: int, ell: int, kind: str, block):
         rows = i * bc + jax.lax.broadcasted_iota(jnp.int32, (bc, bl), 0)
         cols = k * bl + jax.lax.broadcasted_iota(jnp.int32, (bc, bl), 1)
         bits = _threefry_bits_at(key_ref[0, 0], key_ref[0, 1],
-                                 rows * ell + cols, c * ell)
+                                 rows * ell + cols)
         g = _bits_to_generator(bits, kind)
 
         w = w_ref[...]                            # (1, bl)
         x = x_ref[...]                            # (bl, bd)
         xw = x * w[0][:, None].astype(x.dtype)    # fused diagonal scaling
-        out_ref[...] += jax.lax.dot(g, xw,
+        out_ref[...] += jax.lax.dot(g, xw, precision=_F32,
                                     preferred_element_type=jnp.float32
                                     ).astype(out_ref.dtype)
 
@@ -233,7 +231,7 @@ def encode_parity_prng(key: jax.Array, w: jax.Array, x: jax.Array, c: int,
     grid = (c_pad // bc, x.shape[1] // bd, x.shape[0] // bl)
 
     out = pl.pallas_call(
-        _make_prng_kernel(c, ell, kind, (bc, bd, bl)),
+        _make_prng_kernel(ell, kind, (bc, bd, bl)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 2), lambda i, j, k: (0, 0)),

@@ -43,15 +43,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_M = 1024
+_F32 = jax.lax.Precision.HIGHEST
+# Of the 16 MiB of VMEM a v5e kernel gets by default, the double-buffered
+# X blocks may take 14 MiB; the rest holds the row operands, the
+# accumulator and the (bm, 1) residual.
+_X_VMEM_BYTES = 14 * 2**20
+
+
+def _row_tile(block_m: int, rows: int, d: int, streams: int) -> int:
+    """Row tile for `streams` X operands of width d: `block_m`, capped
+    so their double buffers fit `_X_VMEM_BYTES` (d padded to whole
+    128-lane vregs; the cap stays a multiple of 128, as the (1, bm) row
+    blocks need), and never more than the rows there are."""
+    lanes = -(-d // 128) * 128
+    cap = _X_VMEM_BYTES // (2 * streams * lanes * 4) // 128 * 128
+    return min(block_m, max(cap, 128), rows)
+
+
+def _vary_alike(*operands):
+    """Operands cast to vary over every mesh axis any of them varies over
+    (inside `jax.shard_map` a kernel's operands and result must agree on
+    their varying axes), and that axis set.  A type cast: no data moves."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
+    return vma, tuple(
+        jax.lax.pcast(a, tuple(sorted(vma - jax.typeof(a).vma)),
+                      to="varying") if vma - jax.typeof(a).vma else a
+        for a in operands)
 
 
 def _accumulate(x, y, w, beta, out_ref):
-    """out += ((x @ beta - y) * w) @ x for one (bm, d) block."""
-    r = jax.lax.dot_general(x, beta, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32) - y
-    contrib = jax.lax.dot_general(r * w, x, (((0,), (0,)), ((), ())),
+    """out += ((X beta - y) * w) @ X for one (bm, d) block.
+
+    y, w and beta arrive as (1, bm) / (1, d) rows, so both contractions
+    are 2-D matmuls the TPU lowers directly: the residual column
+    (bm, d) x (1, d)^T -> (bm, 1), turned into a row, then
+    (1, bm) x (bm, d) -> (1, d).  HIGHEST keeps the MXU at f32 on the
+    chip (no effect in interpret mode)."""
+    r = jax.lax.dot_general(x, beta, (((1,), (1,)), ((), ())),
+                            precision=_F32,
+                            preferred_element_type=jnp.float32)
+    rw = (r.T - y) * w
+    contrib = jax.lax.dot_general(rw, x, (((1,), (0,)), ((), ())),
+                                  precision=_F32,
                                   preferred_element_type=jnp.float32)
-    out_ref[...] += contrib[None, :].astype(out_ref.dtype)
+    out_ref[...] += contrib.astype(out_ref.dtype)
 
 
 def _masked_kernel(x_ref, y_ref, w_ref, beta_ref, out_ref):
@@ -62,8 +97,7 @@ def _masked_kernel(x_ref, y_ref, w_ref, beta_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    _accumulate(x_ref[...], y_ref[...][0], w_ref[...][0],
-                beta_ref[...][0], out_ref)
+    _accumulate(x_ref[...], y_ref[...], w_ref[...], beta_ref[...], out_ref)
 
 
 def _pad_rows(x, y, w, bm):
@@ -87,9 +121,10 @@ def masked_round_gradient(x: jax.Array, y: jax.Array, w: jax.Array,
     (padding rides at weight 0).
     """
     m, d = x.shape
-    bm = min(block_m, m)
+    bm = _row_tile(block_m, m, d, streams=1)
     x, y, w = _pad_rows(x, y, w, bm)
     grid = (x.shape[0] // bm,)
+    vma, (x, y, w, beta) = _vary_alike(x, y, w, beta)
 
     out = pl.pallas_call(
         _masked_kernel,
@@ -101,7 +136,7 @@ def masked_round_gradient(x: jax.Array, y: jax.Array, w: jax.Array,
             pl.BlockSpec((1, d), lambda i: (0, 0)),       # beta resident
         ],
         out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),  # accumulator
-        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32, vma=vma),
         interpret=interpret,
     )(x, y[None, :], w[None, :], beta[None, :])
     return out[0].astype(beta.dtype)
@@ -117,17 +152,15 @@ def _coded_kernel(nsb, xs_ref, ys_ref, ws_ref, xp_ref, yp_ref, wp_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    beta = beta_ref[...][0]
+    beta = beta_ref[...]
 
     @pl.when(i < nsb)
     def _sys():
-        _accumulate(xs_ref[...], ys_ref[...][0], ws_ref[...][0], beta,
-                    out_ref)
+        _accumulate(xs_ref[...], ys_ref[...], ws_ref[...], beta, out_ref)
 
     @pl.when(i >= nsb)
     def _par():
-        _accumulate(xp_ref[...], yp_ref[...][0], wp_ref[...][0], beta,
-                    out_ref)
+        _accumulate(xp_ref[...], yp_ref[...], wp_ref[...], beta, out_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -143,13 +176,15 @@ def coded_round_gradient(x: jax.Array, y: jax.Array, w: jax.Array,
     """
     m, d = x.shape
     c = x_par.shape[0]
-    bm = min(block_m, max(m, c))
+    bm = _row_tile(block_m, max(m, c), d, streams=2)
     x, y, w = _pad_rows(x, y, w, bm)
     x_par, y_par, w_par = _pad_rows(x_par, y_par, w_par, bm)
     nsb = x.shape[0] // bm
     npb = x_par.shape[0] // bm
     last_s = nsb - 1
     kernel = functools.partial(_coded_kernel, nsb)
+    vma, (x, y, w, x_par, y_par, w_par, beta) = _vary_alike(
+        x, y, w, x_par, y_par, w_par, beta)
 
     out = pl.pallas_call(
         kernel,
@@ -164,7 +199,7 @@ def coded_round_gradient(x: jax.Array, y: jax.Array, w: jax.Array,
             pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32, vma=vma),
         interpret=interpret,
     )(x, y[None, :], w[None, :],
       x_par, y_par[None, :], w_par[None, :], beta[None, :])
@@ -182,8 +217,8 @@ def _tier_kernel(x_ref, y_ref, w_ref, masks_ref, beta_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    w = w_ref[...][0] * masks_ref[...][0]
-    _accumulate(x_ref[...], y_ref[...][0], w, beta_ref[...][0], out_ref)
+    w = w_ref[...] * masks_ref[...]
+    _accumulate(x_ref[...], y_ref[...], w, beta_ref[...], out_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -199,12 +234,13 @@ def tier_masked_round_gradient(x: jax.Array, y: jax.Array, w: jax.Array,
     """
     m, d = x.shape
     t = tier_masks.shape[0]
-    bm = min(block_m, m)
+    bm = _row_tile(block_m, m, d, streams=1)
     pad = (-m) % bm
     x, y, w = _pad_rows(x, y, w, bm)
     if pad:
         tier_masks = jnp.pad(tier_masks, ((0, 0), (0, pad)))
     grid = (x.shape[0] // bm, t)
+    vma, (x, y, w, tier_masks, beta) = _vary_alike(x, y, w, tier_masks, beta)
 
     out = pl.pallas_call(
         _tier_kernel,
@@ -213,11 +249,11 @@ def tier_masked_round_gradient(x: jax.Array, y: jax.Array, w: jax.Array,
             pl.BlockSpec((bm, d), lambda i, t: (i, 0)),   # block resident
             pl.BlockSpec((1, bm), lambda i, t: (0, i)),   # over inner t
             pl.BlockSpec((1, bm), lambda i, t: (0, i)),
-            pl.BlockSpec((1, bm), lambda i, t: (t, i)),   # tier mask slice
+            pl.BlockSpec((None, 1, bm), lambda i, t: (t, 0, i)),  # tier t
             pl.BlockSpec((1, d), lambda i, t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, d), lambda i, t: (t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((t, 1, d), jnp.float32, vma=vma),
         interpret=interpret,
-    )(x, y[None, :], w[None, :], tier_masks, beta[None, :])
-    return out.astype(beta.dtype)
+    )(x, y[None, :], w[None, :], tier_masks[:, None, :], beta[None, :])
+    return out[:, 0].astype(beta.dtype)

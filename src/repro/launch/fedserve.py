@@ -59,10 +59,12 @@ def main(argv=None):
     import jax
 
     from repro.api import TrainData
+    from repro.launch.compile_cache import use_compile_cache
     from repro.serving import (ConvergenceCriterion, FedServeEngine,
                                poisson_arrivals)
     from repro.sim.network import paper_fleet
 
+    use_compile_cache()
     data = TrainData.linreg(jax.random.PRNGKey(args.seed), n=args.n,
                             ell=args.ell, d=args.d)
     fleet = paper_fleet(0.2, 0.2, seed=args.seed, n=args.n, d=args.d)

@@ -526,7 +526,7 @@ def solve_redundancy_batched(requests: Sequence[PlanRequest],
         # requests by keeping the whole solve in float64
         search_f32 = eps_rel >= 1e-5
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             out = _solve_grid(
                 a, mu, tau, p, srv_a, srv_mu, srv_w, caps, srv_cap, target,
                 t_hi0, np.float64(eps_rel),

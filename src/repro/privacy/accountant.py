@@ -153,7 +153,7 @@ def epsilon_spent(noise_multiplier, sample_frac=1.0, rounds=1,
         nm, np.asarray(sample_frac, dtype=np.float64),
         np.asarray(rounds, dtype=np.float64),
         np.asarray(delta, dtype=np.float64))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = np.asarray(_epsilon_spent_grid(*args))
     return float(out) if out.ndim == 0 else out
 
@@ -171,7 +171,7 @@ def epsilon_schedule(noise_multiplier, sample_frac=1.0, rounds=1,
     if nm < 0.0:
         raise ValueError(f"noise_multiplier must be >= 0, got {nm}")
     grid = np.arange(1, int(rounds) + 1, dtype=np.float64)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = np.asarray(_epsilon_schedule_grid(
             np.float64(nm), np.float64(sample_frac), grid,
             np.float64(delta)))

@@ -123,7 +123,7 @@ def calibrate_noise(epsilon_target, delta=1e-5, rounds=1, sample_frac=1.0,
     flat = [np.ascontiguousarray(a).reshape(-1) for a in args]
     frac = np.arange(1, GRID_POINTS + 1, dtype=np.float64) / GRID_POINTS
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         sigma, eps, feasible = (np.asarray(o) for o in _calibrate_grid(
             flat[0], flat[1], flat[2], flat[3],
             np.ones_like(flat[0]), np.float64(eps_rel), frac))

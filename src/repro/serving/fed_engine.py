@@ -60,7 +60,6 @@ from typing import Any, Dict, Hashable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.api import Session, TraceReport, plan_sweep
@@ -147,14 +146,11 @@ def _build_serve_engine(strategy, state, data, shared, carry, dev_b, arr_b,
         return jax.lax.map(lane, (carry_b, dev_bb, arr_bb, ctrl_b))
 
     replicated = jax.tree.map(lambda _: P(), shared)
-    # check_rep=False: shard_map has no replication rule for `while`;
-    # every output here is explicitly lane-sharded anyway.
-    fn = shard_map(lanes, mesh=mesh,
-                   in_specs=(replicated, lane_specs(carry),
-                             lane_specs(dev_b), lane_specs(arr_b),
-                             lane_specs(ctrl)),
-                   out_specs=lane_specs(carry),
-                   check_rep=False)
+    fn = jax.shard_map(lanes, mesh=mesh,
+                       in_specs=(replicated, lane_specs(carry),
+                                 lane_specs(dev_b), lane_specs(arr_b),
+                                 lane_specs(ctrl)),
+                       out_specs=lane_specs(carry))
     return jax.jit(fn, donate_argnums=(1,))
 
 

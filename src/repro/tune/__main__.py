@@ -6,8 +6,8 @@
                                                  # src/repro/tune/defaults.json
 
 Winners land in the user cache (`$REPRO_TUNE_CACHE_DIR/tiles.json`,
-default `~/.cache/repro-tune/tiles.json`); `--ci-defaults` writes the
-in-repo fallback instead (commit the result).  `block="auto"` consults
+which must be set); `--ci-defaults` writes the in-repo fallback instead
+(commit the result).  `block="auto"` consults
 both — this CLI is the ONLY thing that ever autotunes.
 """
 from __future__ import annotations

@@ -14,8 +14,8 @@ Layout — one JSON file per location, schema:
 
 Lookup order (first hit wins):
 
-  1. the user cache — `$REPRO_TUNE_CACHE_DIR/tiles.json`, defaulting to
-     `~/.cache/repro-tune/tiles.json` (written by `python -m repro.tune`);
+  1. the user cache — `$REPRO_TUNE_CACHE_DIR/tiles.json` (written by
+     `python -m repro.tune`), read only when that variable is set;
   2. the in-repo fallback `src/repro/tune/defaults.json`, committed with
      tuned entries for the CPU CI shapes so `block="auto"` hits on fresh
      checkouts and CI runners.
@@ -57,10 +57,11 @@ def cache_key(family: str, shape: tuple[int, ...], backend: str) -> str:
     return f"{family}|{backend}|{bucket}"
 
 
-def user_cache_path() -> str:
-    base = os.environ.get(CACHE_ENV) or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro-tune")
-    return os.path.join(base, CACHE_FILENAME)
+def user_cache_path() -> Optional[str]:
+    """`$REPRO_TUNE_CACHE_DIR/tiles.json`, or None when it is unset: a
+    run then depends on no tile file outside the checkout."""
+    base = os.environ.get(CACHE_ENV)
+    return os.path.join(base, CACHE_FILENAME) if base else None
 
 
 def defaults_path() -> str:
@@ -131,6 +132,8 @@ def lookup_entry(family: str, shape: tuple[int, ...],
         from repro.kernels import common as kcommon
         backend = kcommon.backend()
     for path in (user_cache_path(), defaults_path()):
+        if path is None:
+            continue
         ent = _load_entries(path).get(cache_key(family, shape, backend))
         if ent is not None:
             return ent

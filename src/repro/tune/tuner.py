@@ -38,7 +38,7 @@ import jax
 from repro.kernels import common as kcommon
 from repro.roofline.analysis import roofline_terms
 
-from .cache import TileCache, bucket_shape, user_cache_path
+from .cache import CACHE_ENV, TileCache, bucket_shape, user_cache_path
 from .families import FAMILIES
 
 # How far a candidate's roofline lower bound may sit above the best
@@ -153,7 +153,12 @@ def autotune(family_name: str, shape: tuple, *,
               f"{len(candidates)} candidates, {len(pruned)} pruned, "
               f"winner {winner} at {best_us:.0f}us")
     if store:
-        cache = cache or TileCache(user_cache_path())
+        if cache is None:
+            path = user_cache_path()
+            if path is None:
+                raise ValueError("no tile cache to store into: set "
+                                 f"${CACHE_ENV} or pass cache=")
+            cache = TileCache(path)
         cache.store(family_name, shape, backend, winner, result.meta())
     return result
 

@@ -153,6 +153,37 @@ def test_tier_masked_matches_ref_and_tier_reduce(m, d, t, bm):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("m,d", [(1100, 200), (700, 3000)])
+def test_variants_at_chip_default_tile(m, d):
+    """The TPU's default row tile with a ragged m (zero-weight padding
+    rows) and a d off the 128-lane multiple; at d = 3000 the VMEM cap
+    shrinks the tile (to 512 rows flat, 256 for the two coded streams)."""
+    from repro.kernels.round_grad import round_grad as rg_k
+    bm = rg_k.DEFAULT_BLOCK_M
+    x, y = _rand((m, d), 80), _rand((m,), 81)
+    w, beta = _rand((m,), 82, positive=True), _rand((d,), 83)
+    xp, yp = _rand((m // 3, d), 84), _rand((m // 3,), 85)
+    wp = _rand((m // 3,), 86, positive=True)
+    masks = (jax.random.uniform(jax.random.PRNGKey(87), (3, m)) < 0.5
+             ).astype(x.dtype)
+    pairs = [
+        (rg_ops.masked_round_gradient(x, y, w, beta, block_m=bm,
+                                      force_interpret=True),
+         rg_ref.masked_round_gradient(x, y, w, beta)),
+        (rg_ops.coded_round_gradient(x, y, w, xp, yp, wp, beta, block_m=bm,
+                                     force_interpret=True),
+         rg_ref.coded_round_gradient(x, y, w, xp, yp, wp, beta)),
+        (rg_ops.tier_masked_round_gradient(x, y, w, masks, beta,
+                                           block_m=bm, force_interpret=True),
+         rg_ref.tier_masked_round_gradient(x, y, w, masks, beta)),
+    ]
+    for got, want in pairs:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-3)
+    assert rg_k._row_tile(bm, m, d, streams=1) == (bm if d < 1024 else 512)
+    assert rg_k._row_tile(bm, m, d, streams=2) == (bm if d < 1024 else 256)
+
+
 def test_tier_single_tier_row_is_flat_masked():
     """A ones mask with T == 1 reproduces the flat masked launch bitwise
     (same tile, same accumulation order) — the single-tier-bit-exact

@@ -113,6 +113,27 @@ def test_user_cache_wins_over_defaults(tmp_cache):
         (128, 128, 128)
 
 
+def test_unset_cache_dir_reads_only_committed_defaults(tmp_path,
+                                                       monkeypatch):
+    """With $REPRO_TUNE_CACHE_DIR unset no file outside the checkout is
+    read: a tiles.json under the home directory is ignored."""
+    monkeypatch.delenv(tc.CACHE_ENV, raising=False)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    stray = tc.TileCache(str(tmp_path / ".cache" / "repro-tune" /
+                             tc.CACHE_FILENAME))
+    stray.store("encode", (936, 300, 500), "cpu", (8, 8, 8))
+    stray.store("encode", (64, 48, 32), "cpu", (8, 8, 8))
+    assert tc.user_cache_path() is None
+    repo = tc._load_entries(tc.defaults_path())
+    assert tc.lookup_block("encode", (936, 300, 500), "cpu") == tuple(
+        repo[tc.cache_key("encode", (936, 300, 500), "cpu")]["block"])
+    assert tc.lookup_block("encode", (64, 48, 32), "cpu") is None
+    with pytest.raises(ValueError, match=tc.CACHE_ENV):
+        tuner.autotune("encode", (64, 48, 32),
+                       terms_fn=lambda b: {"t_compute": 1.0, "t_memory": 1.0},
+                       measure_fn=lambda b: 1.0)
+
+
 # ---------------------------------------------------------------------------
 # tuner: pruning + deterministic winner (stubbed terms/measure)
 # ---------------------------------------------------------------------------
