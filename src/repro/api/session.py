@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import aggregation
 
 from .report import TraceReport
@@ -101,10 +102,12 @@ def cache_engine(key: Hashable, build: Callable[[], Callable]) -> Callable:
         _ENGINE_CACHE.move_to_end(key)
         return engine
     engine = build()
+    obs.count("engine_builds")
     _ENGINE_CACHE[key] = engine
     cap = engine_cache_max()
     while len(_ENGINE_CACHE) > cap:
         _ENGINE_CACHE.popitem(last=False)
+        obs.count("engine_evictions")
     return engine
 
 _PRIMITIVES = (bool, int, float, str, bytes, type(None))
@@ -234,7 +237,8 @@ def _build_engine(strategy: Strategy, state: Any, data: TrainData,
                        out_specs=(P("lanes"), P("lanes")))
     # compiled ahead of the call for this bucket's exact shapes, so the
     # engine's program text (`as_text()`) can be inspected
-    return jax.jit(fn).lower(shared, *args).compile()
+    with obs.span("repro.build", lanes=n_lanes):
+        return jax.jit(fn).lower(shared, *args).compile()
 
 
 def _execute_lanes(entries: Sequence[tuple],
@@ -249,41 +253,46 @@ def _execute_lanes(entries: Sequence[tuple],
     devs: List[Dict[str, jax.Array]] = []
     arrs: List[Dict[str, np.ndarray]] = []
     buckets: Dict[Hashable, List[int]] = {}
-    for i, (sess, state, sched) in enumerate(entries):
-        dev = sess.strategy.device_state(state, data)
-        arr = {k: np.asarray(v) for k, v in sched.arrivals.items()}
-        devs.append(dev)
-        arrs.append(arr)
-        key = _bucket_key(sess.strategy, state, data, dev, arr)
-        buckets.setdefault(key, []).append(i)
+    with obs.span("repro.stage", lanes=len(entries)):
+        for i, (sess, state, sched) in enumerate(entries):
+            dev = sess.strategy.device_state(state, data)
+            arr = {k: np.asarray(v) for k, v in sched.arrivals.items()}
+            devs.append(dev)
+            arrs.append(arr)
+            key = _bucket_key(sess.strategy, state, data, dev, arr)
+            buckets.setdefault(key, []).append(i)
 
     dtype = data.xs.dtype
     results: List[Optional[tuple]] = [None] * len(entries)
     for key, idxs in buckets.items():
         b = len(idxs)
         sess0, state0, _ = entries[idxs[0]]
-        # operands the strategy declares as pure functions of `data` are
-        # lane-invariant within one call: pass ONE copy, replicated, and
-        # stack only the genuinely per-lane state
-        data_keys = set(getattr(sess0.strategy, "data_device_keys", ())) \
-            & set(devs[idxs[0]])
-        shared = {k: devs[idxs[0]][k] for k in data_keys}
-        shared["beta_true"] = data.beta_true
-        dev_b = {k: jnp.stack([devs[i][k] for i in idxs])
-                 for k in devs[idxs[0]] if k not in data_keys}
-        arr_b = {k: jnp.asarray(np.stack([arrs[i][k] for i in idxs]))
-                 for k in arrs[idxs[0]]}
-        lr_b = jnp.asarray(np.asarray([entries[i][0].lr for i in idxs]),
-                           dtype=dtype)
-        args = (dev_b, arr_b, lr_b)
+        with obs.span("repro.stage", lanes=b):
+            # operands the strategy declares as pure functions of `data`
+            # are lane-invariant within one call: pass ONE copy,
+            # replicated, and stack only the genuinely per-lane state
+            data_keys = set(getattr(sess0.strategy, "data_device_keys",
+                                    ())) & set(devs[idxs[0]])
+            shared = {k: devs[idxs[0]][k] for k in data_keys}
+            shared["beta_true"] = data.beta_true
+            dev_b = {k: jnp.stack([devs[i][k] for i in idxs])
+                     for k in devs[idxs[0]] if k not in data_keys}
+            arr_b = {k: jnp.asarray(np.stack([arrs[i][k] for i in idxs]))
+                     for k in arrs[idxs[0]]}
+            lr_b = jnp.asarray(
+                np.asarray([entries[i][0].lr for i in idxs]), dtype=dtype)
+            args = (dev_b, arr_b, lr_b)
 
         engine_key = (key, b)
-        engine = cache_engine(
-            engine_key,
-            lambda: _build_engine(sess0.strategy, state0, data, shared,
-                                  args))
-        out_trace, out_beta = engine(shared, *args)
-        out_trace, out_beta = np.asarray(out_trace), np.asarray(out_beta)
+        with obs.span("repro.engine", lanes=b,
+                      builds=int(engine_key not in _ENGINE_CACHE)):
+            engine = cache_engine(
+                engine_key,
+                lambda: _build_engine(sess0.strategy, state0, data, shared,
+                                      args))
+            out_trace, out_beta = engine(shared, *args)
+        with obs.span("repro.fetch", lanes=b):
+            out_trace, out_beta = np.asarray(out_trace), np.asarray(out_beta)
         for j, i in enumerate(idxs):
             results[i] = (out_trace[j], out_beta[j])
             # per-session mirror: introspection + lifetime of the session
@@ -342,7 +351,8 @@ class Session:
     def plan(self, data: TrainData):
         """Run the strategy's one-time setup (exposed so sweeps and
         benchmarks can amortize planning/encoding across runs)."""
-        return self.strategy.plan(self.fleet, data)
+        with obs.span("repro.plan", sessions=1):
+            return self.strategy.plan(self.fleet, data)
 
     def run(self, data: TrainData,
             rng: Optional[np.random.Generator] = None,
@@ -350,14 +360,37 @@ class Session:
         """Plan (unless a pre-planned `state` is given), pre-sample, and
         execute the full training trace — a size-1 batch of the shared
         sweep engine."""
-        if rng is None:
-            rng = np.random.default_rng(self.seed)
-        if state is None:
-            state = self.strategy.plan(self.fleet, data)
-        sched: EpochSchedule = self.strategy.sample_epochs(
-            state, self.fleet, self.epochs, rng)
-        nmse_trace, beta = _execute_lanes([(self, state, sched)], data)[0]
-        return _lane_report(self, state, sched, nmse_trace, label, beta=beta)
+        with obs.span("repro.run", lanes=1):
+            if rng is None:
+                rng = np.random.default_rng(self.seed)
+            if state is None:
+                state = self.plan(data)
+            with obs.span("repro.sample", lanes=1, epochs=self.epochs):
+                sched: EpochSchedule = self.strategy.sample_epochs(
+                    state, self.fleet, self.epochs, rng)
+            nmse_trace, beta = _execute_lanes([(self, state, sched)],
+                                              data)[0]
+            with obs.span("repro.report", lanes=1):
+                return _lane_report(self, state, sched, nmse_trace, label,
+                                    beta=beta)
+
+
+def sample_lanes(lanes: Sequence[tuple]) -> List[EpochSchedule]:
+    """Pre-sample every (session, state, rng) lane's epoch randomness on
+    the host, through the strategy's `sweep_inputs` hook where it has one
+    (else `sample_epochs`), each lane from its own generator.  The sweep
+    and serving engines both sample here."""
+    lanes = list(lanes)
+    # the span's `epochs` is per lane: the mean, where lanes differ
+    lane_epochs = sum(sess.epochs for sess, _, _ in lanes)
+    with obs.span("repro.sample", lanes=len(lanes),
+                  epochs=lane_epochs // max(1, len(lanes))):
+        out = []
+        for sess, state, rng in lanes:
+            sample = getattr(sess.strategy, "sweep_inputs",
+                             sess.strategy.sample_epochs)
+            out.append(sample(state, sess.fleet, sess.epochs, rng))
+        return out
 
 
 def plan_sweep(sessions: Sequence[Session], data: TrainData) -> List[Any]:
@@ -379,21 +412,23 @@ def plan_sweep(sessions: Sequence[Session], data: TrainData) -> List[Any]:
     states: List[Any] = [None] * len(sessions)
     batched: List[int] = []
     requests = []
-    for i, sess in enumerate(sessions):
-        strat = sess.strategy
-        if hasattr(strat, "plan_request") and hasattr(strat, "plan_with") \
-                and getattr(strat, "redundancy_plan", None) is None:
-            requests.append(strat.plan_request(sess.fleet, data))
-            batched.append(i)
-    if requests:
-        from repro.plan import solve_redundancy_batched
-        plans = solve_redundancy_batched(requests)
-        for i, plan in zip(batched, plans):
-            states[i] = sessions[i].strategy.plan_with(
-                sessions[i].fleet, data, plan)
-    for i, sess in enumerate(sessions):
-        if states[i] is None:
-            states[i] = sess.plan(data)
+    with obs.span("repro.plan", sessions=len(sessions)):
+        for i, sess in enumerate(sessions):
+            strat = sess.strategy
+            if hasattr(strat, "plan_request") \
+                    and hasattr(strat, "plan_with") \
+                    and getattr(strat, "redundancy_plan", None) is None:
+                requests.append(strat.plan_request(sess.fleet, data))
+                batched.append(i)
+        if requests:
+            from repro.plan import solve_redundancy_batched
+            plans = solve_redundancy_batched(requests)
+            for i, plan in zip(batched, plans):
+                states[i] = sessions[i].strategy.plan_with(
+                    sessions[i].fleet, data, plan)
+        for i, sess in enumerate(sessions):
+            if states[i] is None:
+                states[i] = sess.strategy.plan(sess.fleet, data)
     return states
 
 
@@ -426,23 +461,21 @@ def run_sweep(sessions: Sequence[Session], data: TrainData,
             or amortize planning separately)
     """
     sessions = list(sessions)
-    if states is None:
-        states = plan_sweep(sessions, data)
-    elif len(states) != len(sessions):
+    if states is not None and len(states) != len(sessions):
         raise ValueError(
             f"got {len(states)} states for {len(sessions)} sessions")
-    if rngs is None:
-        rngs = [np.random.default_rng(sess.seed) for sess in sessions]
-    elif len(rngs) != len(sessions):
+    if rngs is not None and len(rngs) != len(sessions):
         raise ValueError(
             f"got {len(rngs)} generators for {len(sessions)} sessions")
-
-    entries = []
-    for sess, state, rng in zip(sessions, states, rngs):
-        sample = getattr(sess.strategy, "sweep_inputs",
-                         sess.strategy.sample_epochs)
-        entries.append((sess, state,
-                        sample(state, sess.fleet, sess.epochs, rng)))
-    results = _execute_lanes(entries, data)
-    return [_lane_report(sess, state, sched, trace, beta=beta)
-            for (sess, state, sched), (trace, beta) in zip(entries, results)]
+    with obs.span("repro.run", lanes=len(sessions)):
+        if states is None:
+            states = plan_sweep(sessions, data)
+        if rngs is None:
+            rngs = [np.random.default_rng(sess.seed) for sess in sessions]
+        scheds = sample_lanes(zip(sessions, states, rngs))
+        entries = list(zip(sessions, states, scheds))
+        results = _execute_lanes(entries, data)
+        with obs.span("repro.report", lanes=len(entries)):
+            return [_lane_report(sess, state, sched, trace, beta=beta)
+                    for (sess, state, sched), (trace, beta)
+                    in zip(entries, results)]

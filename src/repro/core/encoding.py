@@ -31,6 +31,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -96,7 +98,6 @@ def encode_fleet_streamed(keys: jax.Array, xs: jax.Array, ys: jax.Array,
     return acc[:, :d], acc[:, d]
 
 
-@partial(jax.jit, static_argnames=("c", "kind", "use_kernel"))
 def encode_fleet(key: jax.Array, xs: jax.Array, ys: jax.Array,
                  weights: jax.Array, c: int, kind: str = "normal",
                  use_kernel: bool = False) -> tuple[jax.Array, jax.Array]:
@@ -109,8 +110,19 @@ def encode_fleet(key: jax.Array, xs: jax.Array, ys: jax.Array,
 
     Each client uses an independent fold of `key` — mirroring the protocol
     where G_i is drawn locally and never shared; both paths stream through
-    `encode_fleet_streamed` and therefore draw identical generators.
+    `encode_fleet_streamed` and therefore draw identical generators.  The
+    host span `repro.encode` covers the dispatch; the encode itself runs
+    asynchronously on the device.
     """
+    with obs.span("repro.encode", c=int(c)):
+        return _encode_fleet(key, xs, ys, weights, c, kind=kind,
+                             use_kernel=use_kernel)
+
+
+@partial(jax.jit, static_argnames=("c", "kind", "use_kernel"))
+def _encode_fleet(key: jax.Array, xs: jax.Array, ys: jax.Array,
+                  weights: jax.Array, c: int, kind: str,
+                  use_kernel: bool) -> tuple[jax.Array, jax.Array]:
     keys = jax.random.split(key, xs.shape[0])
     if use_kernel:
         from repro.kernels.encode import ops as encode_ops

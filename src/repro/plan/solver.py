@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.delay_model import (DeviceDelayParams, K_MAX, mec_total_cdf,
                                     total_cdf)
 from repro.core.redundancy import RedundancyPlan
@@ -477,6 +478,12 @@ def solve_redundancy_batched(requests: Sequence[PlanRequest],
     reach its target.
     """
     requests = list(requests)
+    with obs.span("repro.solve", requests=len(requests)):
+        return _solve_batched(requests, eps_rel, grid_points)
+
+
+def _solve_batched(requests: list[PlanRequest], eps_rel: float,
+                   grid_points: int) -> list[RedundancyPlan]:
     plans: list[Optional[RedundancyPlan]] = [None] * len(requests)
     groups: dict[tuple[int, int, bool], list[int]] = {}
     for i, req in enumerate(requests):

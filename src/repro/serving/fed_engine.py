@@ -62,8 +62,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.api import Session, TraceReport, plan_sweep
-from repro.api.session import _bucket_key, cache_engine, make_epoch_step
+from repro.api.session import (_bucket_key, cache_engine, make_epoch_step,
+                               sample_lanes)
 from repro.api.strategy import EpochSchedule
 from repro.core import aggregation
 
@@ -146,12 +148,14 @@ def _build_serve_engine(strategy, state, data, shared, carry, dev_b, arr_b,
         return jax.lax.map(lane, (carry_b, dev_bb, arr_bb, ctrl_b))
 
     replicated = jax.tree.map(lambda _: P(), shared)
-    fn = jax.shard_map(lanes, mesh=mesh,
-                       in_specs=(replicated, lane_specs(carry),
-                                 lane_specs(dev_b), lane_specs(arr_b),
-                                 lane_specs(ctrl)),
-                       out_specs=lane_specs(carry))
-    return jax.jit(fn, donate_argnums=(1,))
+    # jit compiles this program on the group's first step
+    with obs.span("repro.build", lanes=n_lanes):
+        fn = jax.shard_map(lanes, mesh=mesh,
+                           in_specs=(replicated, lane_specs(carry),
+                                     lane_specs(dev_b), lane_specs(arr_b),
+                                     lane_specs(ctrl)),
+                           out_specs=lane_specs(carry))
+        return jax.jit(fn, donate_argnums=(1,))
 
 
 @dataclasses.dataclass
@@ -256,17 +260,18 @@ class _LaneGroup:
         `(slot, prepared, trace_row, exit_epoch, converged, beta)`."""
         self.carry = self.step_fn(self.shared, self.carry, self.dev_b,
                                   self.arr_b, self.ctrl)
-        stop = np.asarray(self.carry[4])
         finished = []
-        for slot, occ in enumerate(self.slots):
-            if occ is None or not stop[slot]:
-                continue
-            t_exit = int(np.asarray(self.carry[1][slot]))
-            trace = np.asarray(self.carry[3][slot])
-            conv = bool(np.asarray(self.carry[5][slot]))
-            beta = np.asarray(self.carry[0][slot])
-            finished.append((slot, occ, trace, t_exit, conv, beta))
-            self.slots[slot] = None
+        with obs.span("repro.fetch", lanes=len(self.slots)):
+            stop = np.asarray(self.carry[4])
+            for slot, occ in enumerate(self.slots):
+                if occ is None or not stop[slot]:
+                    continue
+                t_exit = int(np.asarray(self.carry[1][slot]))
+                trace = np.asarray(self.carry[3][slot])
+                conv = bool(np.asarray(self.carry[5][slot]))
+                beta = np.asarray(self.carry[0][slot])
+                finished.append((slot, occ, trace, t_exit, conv, beta))
+                self.slots[slot] = None
         return finished
 
 
@@ -368,13 +373,12 @@ class FedServeEngine:
         sess = req.session
         state = req.state
         if state is None:
-            state = sess.strategy.plan(sess.fleet, self.data)
-        sample = getattr(sess.strategy, "sweep_inputs",
-                         sess.strategy.sample_epochs)
-        sched = sample(state, sess.fleet, sess.epochs, req.make_rng())
-        dev = sess.strategy.device_state(state, self.data)
-        arr = {k: np.asarray(v) for k, v in sched.arrivals.items()}
-        key = _bucket_key(sess.strategy, state, self.data, dev, arr)
+            state = sess.plan(self.data)
+        sched = sample_lanes([(sess, state, req.make_rng())])[0]
+        with obs.span("repro.stage", lanes=1):
+            dev = sess.strategy.device_state(state, self.data)
+            arr = {k: np.asarray(v) for k, v in sched.arrivals.items()}
+            key = _bucket_key(sess.strategy, state, self.data, dev, arr)
         crit = req.criterion if req.criterion is not None else self.criterion
         hook = getattr(sess.strategy, "serve_convergence", None)
         if hook is not None:
@@ -406,35 +410,39 @@ class FedServeEngine:
             reserved[key] = reserved.get(key, 0) + 1
             return True
 
-        admitted = self._scheduler.pop_admissible(self.now, capacity)
-        for req, key in admitted:
-            prep = self._prepared[req.uid]
-            group = self._groups.get(key)
-            if group is None:
-                group = _LaneGroup(self, key, prep)
-                self._groups[key] = group
-            group.admit(self, prep, group.free_slot())
+        with obs.span("repro.serve.admit") as sp:
+            admitted = self._scheduler.pop_admissible(self.now, capacity)
+            sp.set_metadata(admitted=len(admitted))
+            for req, key in admitted:
+                prep = self._prepared[req.uid]
+                group = self._groups.get(key)
+                if group is None:
+                    group = _LaneGroup(self, key, prep)
+                    self._groups[key] = group
+                group.admit(self, prep, group.free_slot())
         return len(admitted)
 
     def step(self) -> List[TraceReport]:
         """One engine iteration: admit everything that has arrived (whole
         queue scan — no head-of-line blocking), advance every busy group
         one chunk, harvest finished lanes.  Returns the harvest."""
-        self._admit_arrived()
-        if not any(g.running for g in self._groups.values()):
-            nxt = self._scheduler.next_arrival(self.now)
-            if nxt is not None:  # idle: fast-forward to the next arrival
-                self.now = nxt
-                self._admit_arrived()
-        harvested: List[TraceReport] = []
-        for group in self._groups.values():
-            if not group.running:
-                continue
-            for _, prep, trace, t_exit, conv, beta in group.step():
-                report = self._report(prep, trace, t_exit, conv, beta)
-                self._done[prep.request.uid] = report
-                del self._prepared[prep.request.uid]
-                harvested.append(report)
+        with obs.span("repro.serve.step") as sp:
+            self._admit_arrived()
+            if not any(g.running for g in self._groups.values()):
+                # idle: fast-forward to the next arrival
+                nxt = self._scheduler.next_arrival(self.now)
+                if nxt is not None:
+                    self.now = nxt
+                    self._admit_arrived()
+            harvested: List[TraceReport] = []
+            running = [g for g in self._groups.values() if g.running]
+            sp.set_metadata(groups=len(running))
+            for group in running:
+                for _, prep, trace, t_exit, conv, beta in group.step():
+                    report = self._report(prep, trace, t_exit, conv, beta)
+                    self._done[prep.request.uid] = report
+                    del self._prepared[prep.request.uid]
+                    harvested.append(report)
         self.steps += 1
         self.now += self.chunk
         return harvested
