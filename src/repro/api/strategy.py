@@ -22,10 +22,10 @@ einsums on CPU, and the weighting vector is where each scheme's arrival
 semantics live.
 
 Between the two sits the delay machinery: `sample_epochs` pre-samples every
-epoch's delays/arrivals up front on the host (tiny NumPy work, shape
-`(epochs, n)`), preserving the exact draw order of the legacy per-epoch
-loops so old and new entry points produce identical traces from the same
-`np.random.Generator`.
+epoch's delays/arrivals up front on the host in one pass
+(`core.delay_model.sample_epoch_totals`, shape `(epochs, n)`), preserving
+the exact draw order of the legacy per-epoch loops so old and new entry
+points produce identical traces from the same `np.random.Generator`.
 
 Three first-class implementations ship here:
 
@@ -52,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import aggregation, cfl
-from repro.core.delay_model import sample_total
+from repro.core.delay_model import sample_epoch_totals
 from repro.core.gradient_coding import GradCodingPlan, make_plan
 from repro.core.redundancy import RedundancyPlan
 
@@ -243,12 +243,8 @@ class UncodedFL:
 
     def sample_epochs(self, state: UncodedState, fleet: "FleetSpec",
                       epochs: int, rng: np.random.Generator) -> EpochSchedule:
-        durations = np.empty(epochs)
-        # per-epoch host loop preserves the legacy generator draw order
-        for e in range(epochs):
-            t_i = sample_total(fleet.edge, state.loads, rng)
-            durations[e] = float(np.max(t_i))  # wait for all stragglers
-        return EpochSchedule(durations=durations,
+        t, = sample_epoch_totals([(fleet.edge, state.loads)], epochs, rng)
+        return EpochSchedule(durations=t.max(axis=1),  # wait for everyone
                              arrivals={"epoch": np.zeros(epochs, np.float32)})
 
     def device_state(self, state: UncodedState,
@@ -285,6 +281,37 @@ class UncodedFL:
 # ---------------------------------------------------------------------------
 # Coded Federated Learning (the paper's protocol)
 # ---------------------------------------------------------------------------
+
+def coded_epoch_schedule(state, fleet: "FleetSpec", epochs: int,
+                         rng: np.random.Generator, *,
+                         server_always_returns: bool,
+                         include_upload_delay: bool,
+                         mec: bool = False) -> EpochSchedule:
+    """The epochs of a coded scheme with deadline t* (`CodedFL`,
+    `CodedFedL`): the one-time parity upload, drawn first, then per epoch
+    the edge fleet at its loads and, unless the parity always lands
+    (`server_always_returns` or c == 0), the server at its c rows.
+    `received (epochs, n)` marks the clients with a load back by t*;
+    `parity_ok (epochs,)` whether the server made t*."""
+    plan = state.plan
+    t_star = plan.t_star
+    upload_time = cfl.sample_parity_upload_time(state, fleet, rng)
+    groups = [(fleet.edge, plan.loads)]
+    with_server = not (server_always_returns or state.c == 0)
+    if with_server:
+        groups.append((fleet.server, np.array([state.c])))
+    totals = sample_epoch_totals(groups, epochs, rng, mec=mec)
+    received = ((totals[0] <= t_star) & (plan.loads > 0)).astype(np.float32)
+    if with_server:
+        parity_ok = (totals[1][:, 0] <= t_star).astype(np.float32)
+    else:
+        parity_ok = np.ones(epochs, dtype=np.float32)
+    return EpochSchedule(
+        durations=np.full(epochs, t_star),
+        arrivals={"received": received, "parity_ok": parity_ok},
+        setup_time=upload_time,
+        t0=upload_time if include_upload_delay else 0.0)
+
 
 @dataclasses.dataclass(frozen=True)
 class CodedFL:
@@ -355,30 +382,10 @@ class CodedFL:
 
     def sample_epochs(self, state: cfl.CFLState, fleet: "FleetSpec",
                       epochs: int, rng: np.random.Generator) -> EpochSchedule:
-        plan = state.plan
-        n = fleet.edge.n
-        t_star = plan.t_star
-
-        # One-time parity upload, drawn FIRST — the shared helper preserves
-        # the legacy run_cfl generator order
-        upload_time = cfl.sample_parity_upload_time(state, fleet, rng)
-
-        received = np.empty((epochs, n), dtype=np.float32)
-        parity_ok = np.empty(epochs, dtype=np.float32)
-        for e in range(epochs):
-            t_i = sample_total(fleet.edge, plan.loads, rng)
-            received[e] = (t_i <= t_star) & (plan.loads > 0)
-            if self.server_always_returns or state.c == 0:
-                parity_ok[e] = 1.0
-            else:
-                t_srv = sample_total(fleet.server, np.array([state.c]), rng)[0]
-                parity_ok[e] = float(t_srv <= t_star)
-
-        return EpochSchedule(
-            durations=np.full(epochs, t_star),
-            arrivals={"received": received, "parity_ok": parity_ok},
-            setup_time=upload_time,
-            t0=upload_time if self.include_upload_delay else 0.0)
+        return coded_epoch_schedule(
+            state, fleet, epochs, rng,
+            server_always_returns=self.server_always_returns,
+            include_upload_delay=self.include_upload_delay)
 
     def device_state(self, state: cfl.CFLState,
                      data: TrainData) -> Dict[str, jax.Array]:
@@ -498,11 +505,7 @@ class GradientCodingFL:
         n = fleet.edge.n
         # each client processes its whole group's data: r * ell points
         loads = np.full(n, state.plan.r * state.ell)
-        t_all = np.empty((epochs, n))
-        # the per-epoch host loop preserves the legacy generator draw order;
-        # the group reduction below is vectorized across all epochs at once
-        for e in range(epochs):
-            t_all[e] = sample_total(fleet.edge, loads, rng)
+        t_all, = sample_epoch_totals([(fleet.edge, loads)], epochs, rng)
         groups = np.asarray(state.plan.groups)
         per_group = np.full((epochs, state.n_groups), np.inf)
         np.minimum.at(per_group,

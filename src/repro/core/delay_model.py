@@ -21,9 +21,11 @@ the wall-clock simulator).  All functions are vectorized over devices.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro import obs
 
 # Number of retransmission terms kept in the negative-binomial series of the
 # analytic CDF.  With p <= 0.5 the tail Pr{K > 2+K_MAX} is < p^K_MAX * K_MAX,
@@ -267,3 +269,87 @@ def sample_total(params: DeviceDelayParams, ell, rng: np.random.Generator,
     n_u = rng.geometric(1.0 - p, size=shape)
     t_comm = np.where(comm, (n_d + n_u) * params.tau, 0.0)
     return t_c + t_comm
+
+
+def sample_epoch_totals(groups: Sequence[Tuple[DeviceDelayParams, object]],
+                        epochs: int, rng: np.random.Generator,
+                        mec: bool = False) -> List[np.ndarray]:
+    """Draw `epochs` epochs of T_i for a fixed per-epoch sequence of
+    device groups, e.g. `[(edge, loads), (server, [c])]`.
+
+    Returns one (epochs, n_g) array per group, bit for bit the rows of
+
+        for e in range(epochs):
+            for params, ell in groups:
+                sample(params, ell, rng)
+
+    with `sample = sample_total_mec if mec else sample_total`, and leaves
+    `rng` in the same state, so later draws by any caller run on unchanged.
+
+    Draw order, per epoch and then per group in turn (the benchmark's
+    reference sampler mirrors it):
+
+    * base model: n standard exponentials (the compute excess), then 2n
+      Geometric(1 - p) transmission counts, all n downlink counts before
+      all n uplink counts; a device with tau == 0 draws Geometric(1);
+    * MEC model: n standard exponentials (the compute excess), then n
+      more (the communication excess).
+
+    `standard_exponential` is `exponential(1.0)` draw for draw, and
+    consecutive calls of one distribution draw the same words as one call
+    of their joined size.  The set-up of each group is built once; when
+    every device of a group has the same success probability, the
+    geometric draws take it as a scalar, which runs the same per-draw
+    routine without numpy's per-call broadcasting.  The arithmetic runs
+    after the loop, over whole (epochs, n) arrays, with the float64
+    operations of the per-call samplers in their order.
+
+    Counts each base-model group drawn in `obs` as `sample_groups_uniform`
+    (one success probability) or `sample_groups_mixed` (per device); MEC
+    groups draw no geometric and count in neither.
+    """
+    std_exp, geometric = rng.standard_exponential, rng.geometric
+    plans = []
+    for params, ell in groups:
+        ell = np.broadcast_to(np.asarray(ell, dtype=np.float64),
+                              params.a.shape)
+        with np.errstate(divide="ignore", invalid="ignore"):
+            scale = np.where(ell > 0, ell / params.mu, 0.0)
+        comm = params.tau > 0
+        if mec:
+            draws = np.empty((epochs, 2, params.n))
+            q = None
+        else:
+            draws = (np.empty((epochs, params.n)),
+                     np.empty((epochs, 2, params.n), dtype=np.int64))
+            q = 1.0 - np.where(comm, params.p, 0.0)
+            uniform = bool(np.all(q == q[0]))
+            if uniform:
+                q = float(q[0])
+            obs.count("sample_groups_uniform" if uniform
+                      else "sample_groups_mixed")
+        plans.append((params, ell * params.a, scale, comm, q, draws))
+
+    for e in range(epochs):
+        for params, _, _, _, q, draws in plans:
+            if mec:
+                std_exp(out=draws[e])
+            else:
+                std_exp(out=draws[0][e])
+                draws[1][e] = geometric(q, size=(2, params.n))
+
+    totals = []
+    for params, shift, scale, comm, _, draws in plans:
+        if mec:
+            t_c = shift + draws[:, 0] * scale
+            stochastic = np.logical_and(comm, params.p > 0)
+            gm = (1.0 - params.p) / np.maximum(
+                2.0 * params.tau * params.p, 1e-30)
+            t_comm = np.where(comm, 2.0 * params.tau, 0.0) \
+                + np.where(stochastic, draws[:, 1] / gm, 0.0)
+        else:
+            t_c = shift + draws[0] * scale
+            n_d, n_u = draws[1][:, 0], draws[1][:, 1]
+            t_comm = np.where(comm, (n_d + n_u) * params.tau, 0.0)
+        totals.append(t_c + t_comm)
+    return totals

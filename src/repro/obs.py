@@ -17,10 +17,12 @@ would fire once, at trace time -- and they never wait for the device.
 
 `SPANS` names every span the program opens; readers of a trace key on it.
 
-Counters are process-wide counts of rare events that no single call
-explains: `engine_builds` (compiled engines built by the shared engine
-cache) and `engine_evictions` (engines the cache dropped at its cap).
-`counters()` returns a copy.
+Counters are process-wide counts that no single call explains:
+`engine_builds` (compiled engines built by the shared engine cache),
+`engine_evictions` (engines the cache dropped at its cap), and
+`sample_groups_uniform` / `sample_groups_mixed` (device groups whose
+epoch delays `core.delay_model.sample_epoch_totals` drew with one
+success probability / with one per device).  `counters()` returns a copy.
 """
 from __future__ import annotations
 
@@ -44,7 +46,9 @@ SPANS = (
 )
 _NAMES = frozenset(SPANS)
 
-_COUNTS: Dict[str, int] = {"engine_builds": 0, "engine_evictions": 0}
+_COUNTS: Dict[str, int] = {"engine_builds": 0, "engine_evictions": 0,
+                           "sample_groups_uniform": 0,
+                           "sample_groups_mixed": 0}
 
 
 def span(name: str, **counts: int) -> TraceAnnotation:

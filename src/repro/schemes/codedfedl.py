@@ -21,7 +21,8 @@ Two ideas ride on the CFL machinery:
      with `PlanRequest.mec_comm=True`: expected returns use the
      closed-form two-exponential convolution CDF, and the Eq.-17 weights
      see the same probabilities via `core.delay_model.mec_total_cdf`.
-     Wall-clock epochs sample from `sample_total_mec`.
+     Wall-clock epochs sample the same model (`sample_epoch_totals`
+     with `mec=True`, draw for draw `sample_total_mec`).
 
 The classification recipe (paper §V): labels from
 `repro.data.classification_dataset`, one-vs-rest ±1 targets via
@@ -41,9 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.api.strategy import EpochSchedule, TrainData
+from repro.api.strategy import EpochSchedule, TrainData, coded_epoch_schedule
 from repro.core import aggregation, cfl
-from repro.core.delay_model import sample_total, sample_total_mec
 from repro.core.redundancy import RedundancyPlan
 from repro.data.rff import rff_map
 
@@ -175,34 +175,14 @@ class CodedFedL:
 
     def sample_epochs(self, state: CodedFedLState, fleet: "FleetSpec",
                       epochs: int, rng: np.random.Generator) -> EpochSchedule:
-        plan = state.plan
-        n = fleet.edge.n
-        t_star = plan.t_star
         # MEC epochs draw from the shifted-exponential model the solve
-        # optimized; the base sampler keeps the degenerate path bit-equal
+        # optimized; the base model keeps the degenerate path bit-equal
         # to CodedFL's arrival stream
-        sampler = sample_total_mec if self._mec() else sample_total
-
-        # One-time parity upload, drawn FIRST — the shared helper preserves
-        # the legacy run_cfl generator order
-        upload_time = cfl.sample_parity_upload_time(state, fleet, rng)
-
-        received = np.empty((epochs, n), dtype=np.float32)
-        parity_ok = np.empty(epochs, dtype=np.float32)
-        for e in range(epochs):
-            t_i = sampler(fleet.edge, plan.loads, rng)
-            received[e] = (t_i <= t_star) & (plan.loads > 0)
-            if self.server_always_returns or state.c == 0:
-                parity_ok[e] = 1.0
-            else:
-                t_srv = sampler(fleet.server, np.array([state.c]), rng)[0]
-                parity_ok[e] = float(t_srv <= t_star)
-
-        return EpochSchedule(
-            durations=np.full(epochs, t_star),
-            arrivals={"received": received, "parity_ok": parity_ok},
-            setup_time=upload_time,
-            t0=upload_time if self.include_upload_delay else 0.0)
+        return coded_epoch_schedule(
+            state, fleet, epochs, rng,
+            server_always_returns=self.server_always_returns,
+            include_upload_delay=self.include_upload_delay,
+            mec=self._mec())
 
     # -- engine hooks -------------------------------------------------------
 

@@ -12,11 +12,12 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st  # hypothesis, or a deterministic fallback
 
-from repro.api import (CodedFL, GradientCodingFL, Session, TraceReport,
-                       TrainData, UncodedFL, coding_gain, convergence_time)
+from repro.api import (CodedFL, EpochSchedule, GradientCodingFL, Session,
+                       TraceReport, TrainData, UncodedFL, coding_gain,
+                       convergence_time)
 from repro.core import aggregation, cfl
 from repro.core.delay_model import sample_total
-from repro.sim.network import paper_fleet
+from repro.sim.network import paper_fleet, wireless_fleet
 
 
 @pytest.fixture(scope="module")
@@ -160,6 +161,91 @@ def test_gradcoding_session_matches_legacy_trace(small):
     unc = Session(strategy=UncodedFL(), fleet=fleet, lr=0.05,
                   epochs=60).run(data, rng=np.random.default_rng(0))
     np.testing.assert_allclose(rep.nmse, unc.nmse, rtol=2e-4, atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# epoch schedules == the per-epoch sampling loops they replaced
+# ---------------------------------------------------------------------------
+
+def _frozen_uncoded_epochs(state, fleet, epochs, rng):
+    """`UncodedFL.sample_epochs` as a per-epoch loop (frozen copy)."""
+    durations = np.empty(epochs)
+    for e in range(epochs):
+        t_i = sample_total(fleet.edge, state.loads, rng)
+        durations[e] = float(np.max(t_i))
+    return EpochSchedule(durations=durations,
+                         arrivals={"epoch": np.zeros(epochs, np.float32)})
+
+
+def _frozen_coded_epochs(strategy, state, fleet, epochs, rng):
+    """`CodedFL.sample_epochs` as a per-epoch loop (frozen copy)."""
+    plan = state.plan
+    n = fleet.edge.n
+    t_star = plan.t_star
+    upload_time = cfl.sample_parity_upload_time(state, fleet, rng)
+    received = np.empty((epochs, n), dtype=np.float32)
+    parity_ok = np.empty(epochs, dtype=np.float32)
+    for e in range(epochs):
+        t_i = sample_total(fleet.edge, plan.loads, rng)
+        received[e] = (t_i <= t_star) & (plan.loads > 0)
+        if strategy.server_always_returns or state.c == 0:
+            parity_ok[e] = 1.0
+        else:
+            t_srv = sample_total(fleet.server, np.array([state.c]), rng)[0]
+            parity_ok[e] = float(t_srv <= t_star)
+    return EpochSchedule(
+        durations=np.full(epochs, t_star),
+        arrivals={"received": received, "parity_ok": parity_ok},
+        setup_time=upload_time,
+        t0=upload_time if strategy.include_upload_delay else 0.0)
+
+
+def assert_same_schedule(got, want):
+    np.testing.assert_array_equal(got.durations, want.durations)
+    assert got.durations.dtype == want.durations.dtype
+    assert got.arrivals.keys() == want.arrivals.keys()
+    for k, v in want.arrivals.items():
+        np.testing.assert_array_equal(got.arrivals[k], v)
+        assert got.arrivals[k].dtype == v.dtype, k
+    assert (got.setup_time, got.t0) == (want.setup_time, want.t0)
+
+
+@pytest.mark.parametrize("fleet_kind", ["paper", "wireless"])
+@pytest.mark.parametrize("variant", [
+    "uncoded", "cfl", "cfl_server_always_returns", "cfl_c0",
+    "cfl_no_upload_delay"])
+def test_epoch_schedule_matches_frozen_loop(small, variant, fleet_kind):
+    """Each strategy's `sample_epochs` and `sweep_inputs` give the frozen
+    per-epoch loop's schedule bit for bit and leave the generator where
+    the loop leaves it."""
+    _, data = small
+    if fleet_kind == "paper":
+        fleet = paper_fleet(0.2, 0.2, seed=2, n=data.n, d=data.d)
+    else:
+        fleet = wireless_fleet(0.2, 0.2, nu_erasure=0.3, seed=2, n=data.n,
+                               d=data.d)
+    if variant == "uncoded":
+        strat = UncodedFL()
+    else:
+        strat = CodedFL(
+            key=jax.random.PRNGKey(4),
+            fixed_c=0 if variant == "cfl_c0" else int(0.3 * data.m),
+            server_always_returns=variant == "cfl_server_always_returns",
+            include_upload_delay=variant != "cfl_no_upload_delay")
+    state = strat.plan(fleet, data)
+    if variant == "cfl_c0":
+        assert state.c == 0
+    epochs = 150
+    seed = 2**32 + 5
+    rng_old = np.random.default_rng(seed)
+    if variant == "uncoded":
+        want = _frozen_uncoded_epochs(state, fleet, epochs, rng_old)
+    else:
+        want = _frozen_coded_epochs(strat, state, fleet, epochs, rng_old)
+    for draw in (strat.sample_epochs, strat.sweep_inputs):
+        rng = np.random.default_rng(seed)
+        assert_same_schedule(draw(state, fleet, epochs, rng), want)
+        assert rng.bit_generator.state == rng_old.bit_generator.state
 
 
 # ---------------------------------------------------------------------------

@@ -84,6 +84,51 @@ def test_vectorized_sample_epochs_matches_legacy_loop():
     assert np.all(sched.arrivals["group_ok"] == 1.0)
 
 
+@pytest.mark.parametrize("r", [1, 3])
+@pytest.mark.parametrize("fleet_kind", ["paper", "wireless"])
+def test_sample_epochs_matches_frozen_loop(fleet_kind, r):
+    """`GradientCodingFL.sample_epochs` (and `sweep_inputs`) against a
+    frozen copy of its per-epoch sampling loop: the same schedule bit for
+    bit, and the generator left where the loop leaves it."""
+    from repro.api import GradientCodingFL, TrainData
+    from repro.core.delay_model import sample_total
+    from repro.sim.network import wireless_fleet
+
+    if fleet_kind == "paper":
+        fleet = paper_fleet(0.2, 0.2, seed=4, n=12, d=50)
+    else:
+        fleet = wireless_fleet(0.2, 0.2, nu_erasure=0.3, seed=4, n=12, d=50)
+    data = TrainData(*[jax.numpy.asarray(v) for v in
+                       S.generate_linreg(jax.random.PRNGKey(0),
+                                         n=12, ell=30, d=50)])
+    strat = GradientCodingFL(r=r)
+    state = strat.plan(fleet, data)
+    epochs = 120
+    seed = 2**31 + 3
+
+    rng_old = np.random.default_rng(seed)
+    n = fleet.edge.n
+    loads = np.full(n, state.plan.r * state.ell)
+    t_all = np.empty((epochs, n))
+    for e in range(epochs):
+        t_all[e] = sample_total(fleet.edge, loads, rng_old)
+    groups = np.asarray(state.plan.groups)
+    per_group = np.full((epochs, state.n_groups), np.inf)
+    np.minimum.at(per_group,
+                  (np.arange(epochs)[:, None], groups[None, :]), t_all)
+    durations = per_group.max(axis=1)
+
+    for draw in (strat.sample_epochs, strat.sweep_inputs):
+        rng = np.random.default_rng(seed)
+        sched = draw(state, fleet, epochs, rng)
+        np.testing.assert_array_equal(sched.durations, durations)
+        np.testing.assert_array_equal(
+            sched.arrivals["group_ok"],
+            np.ones((epochs, state.n_groups), np.float32))
+        assert sched.setup_time == sched.t0 == state.shard_time
+        assert rng.bit_generator.state == rng_old.bit_generator.state
+
+
 def test_gradient_coding_converges():
     fleet = paper_fleet(0.2, 0.2, seed=1, n=12, d=60)
     key = jax.random.PRNGKey(0)

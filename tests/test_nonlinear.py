@@ -30,7 +30,9 @@ from _hyp import given, settings, st  # hypothesis, or a deterministic fallback
 
 from benchmarks.perf_trend import classify
 from repro.api import Session, TrainData, make_strategy, run_sweep
-from repro.core.delay_model import mec_total_cdf, sample_total_mec
+from repro.core import cfl as cfl_core
+from repro.core.delay_model import (mec_total_cdf, sample_total,
+                                    sample_total_mec)
 from repro.data import (classification_dataset, one_vs_rest_targets,
                         rff_map, rff_map_reference)
 from repro.fleet import FleetTopology
@@ -38,7 +40,7 @@ from repro.plan import PlanRequest, solve_redundancy_batched
 from repro.plan.reference_schemes import solve_codedfedl_reference
 from repro.schemes import CodedFedL
 from repro.serving import ConvergenceCriterion, FedServeEngine
-from repro.sim.network import wireless_fleet
+from repro.sim.network import paper_fleet, wireless_fleet
 
 from test_schemes import _random_fleet
 
@@ -236,6 +238,62 @@ def test_codedfedl_identity_map_degenerates_to_cfl(linreg_small):
     np.testing.assert_array_equal(r_f.times, r_c.times)
     np.testing.assert_array_equal(r_f.epoch_durations, r_c.epoch_durations)
     assert r_f.setup_time == r_c.setup_time
+
+
+def _frozen_codedfedl_epochs(strategy, state, fleet, epochs, rng, mec):
+    """`CodedFedL.sample_epochs` as a per-epoch loop (frozen copy)."""
+    plan = state.plan
+    n = fleet.edge.n
+    t_star = plan.t_star
+    sampler = sample_total_mec if mec else sample_total
+    upload_time = cfl_core.sample_parity_upload_time(state, fleet, rng)
+    received = np.empty((epochs, n), dtype=np.float32)
+    parity_ok = np.empty(epochs, dtype=np.float32)
+    for e in range(epochs):
+        t_i = sampler(fleet.edge, plan.loads, rng)
+        received[e] = (t_i <= t_star) & (plan.loads > 0)
+        if strategy.server_always_returns or state.c == 0:
+            parity_ok[e] = 1.0
+        else:
+            t_srv = sampler(fleet.server, np.array([state.c]), rng)[0]
+            parity_ok[e] = float(t_srv <= t_star)
+    return received, parity_ok, upload_time, \
+        upload_time if strategy.include_upload_delay else 0.0
+
+
+@pytest.mark.parametrize("server_always_returns", [False, True],
+                         ids=["server", "no_server"])
+@pytest.mark.parametrize("fleet_kind", ["paper", "wireless"])
+@pytest.mark.parametrize("mec", [False, True], ids=["base", "mec"])
+def test_codedfedl_epochs_match_frozen_loop(linreg_small, mec, fleet_kind,
+                                            server_always_returns):
+    """CodedFedL's schedule on either delay model, bit for bit the frozen
+    per-epoch loop's, with the generator left where the loop leaves it."""
+    _, data = linreg_small
+    if fleet_kind == "paper":
+        fleet = paper_fleet(0.2, 0.2, seed=6, n=N, d=data.d)
+    else:
+        fleet = wireless_fleet(0.2, 0.2, nu_erasure=0.3, seed=6, n=N,
+                               d=data.d)
+    strat = CodedFedL(key=jax.random.PRNGKey(8), mec_comm=mec,
+                      fixed_c=int(0.25 * data.m),
+                      server_always_returns=server_always_returns)
+    state = strat.plan(fleet, data)
+    epochs, seed = 90, 2**31 + 7
+    rng_old = np.random.default_rng(seed)
+    received, parity_ok, setup, t0 = _frozen_codedfedl_epochs(
+        strat, state, fleet, epochs, rng_old, mec)
+    for draw in (strat.sample_epochs, strat.sweep_inputs):
+        rng = np.random.default_rng(seed)
+        sched = draw(state, fleet, epochs, rng)
+        np.testing.assert_array_equal(sched.arrivals["received"], received)
+        np.testing.assert_array_equal(sched.arrivals["parity_ok"], parity_ok)
+        assert sched.arrivals["received"].dtype == np.float32
+        assert sched.arrivals["parity_ok"].dtype == np.float32
+        np.testing.assert_array_equal(sched.durations,
+                                      np.full(epochs, state.plan.t_star))
+        assert (sched.setup_time, sched.t0) == (setup, t0)
+        assert rng.bit_generator.state == rng_old.bit_generator.state
 
 
 # ---------------------------------------------------------------------------

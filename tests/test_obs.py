@@ -10,7 +10,7 @@ import pytest
 from repro import obs
 from repro.api import Session, TrainData, make_strategy, run_sweep
 from repro.serving import FedServeEngine
-from repro.sim.network import paper_fleet
+from repro.sim.network import paper_fleet, wireless_fleet
 
 EPOCHS = 12
 
@@ -165,3 +165,32 @@ def test_engine_cache_counters(monkeypatch, small):
     # a copy: changing it changes nothing
     after["engine_builds"] = -1
     assert obs.counters()["engine_builds"] >= 3
+
+
+@pytest.mark.parametrize("strategy, fleet_kind, uniform, mixed", [
+    ("cfl", "paper", 2, 0),        # edge fleet, then the tau = 0 server
+    ("uncoded", "paper", 1, 0),
+    ("cfl", "wireless", 1, 1),     # per-device erasure on the edge
+])
+def test_sample_group_counters(small, strategy, fleet_kind, uniform, mixed):
+    """One solo session counts each device group its epochs draw, by
+    whether the group's geometric draws take one success probability."""
+    _, data = small
+    if fleet_kind == "paper":
+        fleet = paper_fleet(0.2, 0.2, seed=1, n=data.n, d=data.d)
+    else:
+        fleet = wireless_fleet(0.2, 0.2, nu_erasure=0.3, seed=1, n=data.n,
+                               d=data.d)
+    if strategy == "cfl":
+        sess = _cfl(fleet, data, 41)
+    else:
+        sess = Session(strategy=make_strategy("uncoded"), fleet=fleet,
+                       lr=0.05, epochs=EPOCHS)
+    state = sess.plan(data)
+    before = obs.counters()
+    sess.run(data, rng=np.random.default_rng(2), state=state)
+    after = obs.counters()
+    assert (after["sample_groups_uniform"]
+            - before["sample_groups_uniform"]) == uniform
+    assert (after["sample_groups_mixed"]
+            - before["sample_groups_mixed"]) == mixed
