@@ -40,11 +40,12 @@ def control_numbers(workload: str, seed: int, lanes: int) -> list:
     traffic = run.load_json(HERE, "traffic", cell["traffic"] + ".json")
     driver = run.load_module(os.path.join(HERE, "traffic",
                                           traffic["driver"] + ".py"))
-    ctx = run.Ctx(system=build(cfg, seed), traffic=traffic, seed=seed,
-                  chips=cell["chips"])
+    ctx = run.Ctx(system=build(cfg, seed, traffic.get("fleet_seed")),
+                  traffic=traffic, seed=seed, chips=cell["chips"])
     out = []
     for name, key, rng, over in driver.compared(ctx)[:lanes]:
-        ans = checks.reference_answer(ctx.system, name, key, rng, over, HIGH)
+        ans = ctx.system.reference.answer(ctx.system, name, key, rng, over,
+                                          HIGH)
         out.append({"strategy": name, **over,
                     **checks.compare(ctx.system, ans)})
     return out

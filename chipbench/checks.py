@@ -9,6 +9,16 @@ from the configuration file.
     nums = compare(system, answer)           # {"nmse_rel": ..., ...}
     checks, ok = verdict(system.cfg, [nums, ...])
 
+The reference is the module the configuration names (`"reference":
+"<name>"`, `chipbench/reference/<name>.py`, `system.reference`), which
+imports nothing of `src/` and exposes
+
+    answer(system, name, key, rng, overrides, ar, t_star=None) -> Answer
+    root(system, spec)   the least deadline t_root of a coded session's
+                         plan, or None for an uncoded one
+    work(system, name, rng, plan) -> count.Work of the session's epochs,
+                         from the arrival masks `answer` draws for it
+
 The numbers:
   t_star_gap    (t*_program - t_root) / t_root: Eq. 16 holds at t* and
                 t* lies within the configuration's plan_eps_rel of the
@@ -16,7 +26,7 @@ The numbers:
   loads_diff    clients whose load differs from the reference's best load
                 at the program's t* (exact: limit 0)
   p_return_gap  largest |Pr{T_i <= t*}| difference at those loads
-  parity_rel    largest |[X~, y~] - reference| over the largest reference
+  parity_rel    largest |[X~, Y~] - reference| over the largest reference
                 entry
   clock_diff    largest |time| difference of the snapshots' clock (exact)
   nmse_rel      largest relative difference of the NMSE over all epochs
@@ -30,7 +40,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from reference import FLOAT64, Arith
-from reference import cfl as ref
 
 
 @dataclasses.dataclass
@@ -47,7 +56,7 @@ class Answer:
     t_star: Optional[float] = None
     loads: Optional[np.ndarray] = None
     p_return: Optional[np.ndarray] = None
-    parity: Optional[np.ndarray] = None  # (c, d + 1): [X~, y~]
+    parity: Optional[np.ndarray] = None  # (c, d + K): [X~, Y~]
 
 
 def program_answer(name, key, rng, overrides, state, report) -> Answer:
@@ -59,14 +68,12 @@ def program_answer(name, key, rng, overrides, state, report) -> Answer:
         ans.t_star = float(plan.t_star)
         ans.loads = np.asarray(plan.loads)
         ans.p_return = np.asarray(plan.p_return)
+        x_par = np.asarray(state.x_parity, np.float64)
         ans.parity = np.concatenate(
-            [np.asarray(state.x_parity, np.float64),
-             np.asarray(state.y_parity, np.float64)[:, None]], axis=1)
+            [x_par, np.asarray(state.y_parity,
+                               np.float64).reshape(x_par.shape[0], -1)],
+            axis=1)
     return ans
-
-
-def _spec(system, name, overrides) -> Dict[str, Any]:
-    return dict(system.cfg["strategies"][name], **overrides)
 
 
 def _host_data(system):
@@ -77,72 +84,15 @@ def _host_data(system):
     return system.cache["host"]
 
 
-# the delay model of each coded strategy kind
-MODEL = {"cfl": "base", "codedfedl": "mec"}
-
-
-def _features(system, ar: Arith) -> np.ndarray:
-    """The data's random Fourier features, computed by the reference
-    (kept per precision: every answer of a run shares them)."""
-    import jax
-
-    cache = system.cache.setdefault("features", {})
-    if ar.name not in cache:
-        head = system.cfg["data"]["head"]
-        cache[ar.name] = ref.rff(_host_data(system)[0],
-                                 jax.random.PRNGKey(system.rff_key),
-                                 head["d_feat"], head["rff_gamma"], ar)
-    return cache[ar.name]
-
-
 def reference_answer(system, name: str, key: int, rng: int,
                      overrides: Dict[str, Any], ar: Arith,
                      t_star: Optional[float] = None) -> Answer:
-    """The session computed by the reference in the precision `ar`.  A
-    coded session takes its deadline from `t_star` where given (the
-    program's, which `t_star_gap` judges), else solves Eq. 16 itself."""
-    import jax
-
-    cfg = system.cfg
-    spec = _spec(system, name, overrides)
-    xs, ys, bt = _host_data(system)
-    n, ell, d = xs.shape
-    epochs, lr = cfg["epochs"], cfg["lr"]
-    gen = np.random.default_rng(rng)
-    x, y = xs.reshape(n * ell, d), ys.reshape(n * ell)
-    row_client = np.repeat(np.arange(n), ell)
-    ans = Answer(name, key, rng, dict(overrides), None, None, None)
-    if spec["kind"] == "uncoded":
-        sched = ref.sample_uncoded(system.ref_fleet, ell, epochs, gen)
-        nmse, beta = ref.train(ar, x, y, bt, lr, np.ones(n * ell),
-                               row_client, sched.received)
-    elif spec["kind"] in MODEL:
-        model = MODEL[spec["kind"]]
-        if spec.get("head"):
-            xs = _features(system, ar)
-            d = xs.shape[-1]
-            x = xs.reshape(n * ell, d)
-        c = int(spec["fixed_c"])
-        if t_star is None:
-            t_star = ref.deadline(system.ref_fleet, system.sizes, c, ar,
-                                  model)
-        plan = ref.plan_at(system.ref_fleet, system.sizes, c, t_star, ar,
-                           model)
-        w = ref.weights(plan, ell)
-        xp, yp = ref.encode(jax.random.PRNGKey(key), xs, ys, w, c, ar)
-        sched = ref.sample_coded(system.ref_fleet, plan, d, epochs, gen,
-                                 model)
-        rows = (np.arange(ell)[None, :] < plan.loads[:, None]).reshape(-1)
-        nmse, beta = ref.train(ar, x, y, bt, lr, rows.astype(np.float64),
-                               row_client, sched.received, (xp, yp),
-                               sched.parity_ok)
-        ans.t_star, ans.loads, ans.p_return = t_star, plan.loads, \
-            plan.p_return
-        ans.parity = np.concatenate([xp, yp[:, None]], 1).astype(np.float64)
-    else:
-        raise ValueError(f"no reference for strategy kind {spec['kind']!r}")
-    ans.nmse, ans.beta, ans.times = nmse, beta, sched.times
-    return ans
+    """The session computed by the configuration's reference module in
+    the precision `ar`.  A coded session takes its deadline from `t_star`
+    where given (the program's, which `t_star_gap` judges), else solves
+    for it itself."""
+    return system.reference.answer(system, name, key, rng, overrides, ar,
+                                   t_star)
 
 
 def _rel_max(a, b) -> float:
@@ -155,13 +105,9 @@ def compare(system, ans: Answer) -> Dict[str, float]:
                                ans.overrides, FLOAT64, t_star=ans.t_star)
     out: Dict[str, float] = {}
     if ans.t_star is not None:
-        spec = _spec(system, ans.name, ans.overrides)
-        c = int(spec["fixed_c"])
-        roots = system.cache.setdefault("roots", {})
-        if c not in roots:
-            roots[c] = ref.deadline(system.ref_fleet, system.sizes, c,
-                                    model=MODEL[spec["kind"]])
-        out["t_star_gap"] = (ans.t_star - roots[c]) / roots[c]
+        root = system.reference.root(system,
+                                     system.spec(ans.name, ans.overrides))
+        out["t_star_gap"] = (ans.t_star - root) / root
         out["loads_diff"] = float(np.sum(ans.loads != ref_ans.loads))
         out["p_return_gap"] = float(np.max(np.abs(
             ans.p_return - ref_ans.p_return)))
@@ -169,8 +115,9 @@ def compare(system, ans: Answer) -> Dict[str, float]:
     out["clock_diff"] = float(np.max(np.abs(ans.times - ref_ans.times)))
     out["nmse_rel"] = float(np.max(np.abs(ans.nmse - ref_ans.nmse)
                                    / ref_ans.nmse))
-    out["beta_rel"] = float(np.linalg.norm(ans.beta - ref_ans.beta)
-                            / np.linalg.norm(ref_ans.beta))
+    beta, ref_beta = np.ravel(ans.beta), np.ravel(ref_ans.beta)
+    out["beta_rel"] = float(np.linalg.norm(beta - ref_beta)
+                            / np.linalg.norm(ref_beta))
     return out
 
 

@@ -1,18 +1,18 @@
 """Operations and bytes of training epochs, counted from the problem.
 
-    w = epoch_work(d, row_counts, parity_rows, parity_ok)
+    w = epoch_work(d, row_counts, parity_rows, parity_ok, outputs=K)
     w.sys_flops, w.sys_bytes     # the masked round gradient over X
     w.flops, w.bytes             # plus the parity term
 
 The count never reads the program's layout.  For each epoch it takes the
 rows of the clients whose update counts in that epoch's arrival mask,
-each row of X read once (d features and its label, 4 bytes each): the
-residual and the gradient are 2 d operations a row each.  The parity
-term counts only in epochs where it arrives, its operations and its
-bytes each at the lesser of its raw form (c rows of d + 1 read, 4 c d
-operations) and its Gram-folded form (d x (d + 1) read, 2 d^2).  So it
-is a lower bound on the work: no packing, skipping or folding a program
-does can push a share of a peak past 100%.
+each row of X read once (d features and its K labels, 4 bytes each): the
+residual and the gradient of a (d, K) head are 2 d K operations a row
+each.  The parity term counts only in epochs where it arrives, its
+operations and its bytes each at the lesser of its raw form (c rows of
+d + K read, 4 c d K operations) and its Gram-folded form (d x (d + K)
+read, 2 d^2 K).  So it is a lower bound on the work: no packing,
+skipping or folding a program does can push a share of a peak past 100%.
 """
 from __future__ import annotations
 
@@ -49,23 +49,25 @@ NONE = Work(0.0, 0.0, 0.0, 0.0)
 
 
 def epoch_work(d: int, row_counts: np.ndarray, parity_rows: int = 0,
-               parity_ok=None) -> Work:
+               parity_ok=None, *, outputs: int) -> Work:
     """Work of a run of epochs.
 
     d: feature width.  row_counts: (E,) rows whose update counts in each
     epoch.  parity_rows: c (0 for no parity).  parity_ok: (E,) 1 where
-    the parity gradient arrives in time.  The block the epochs read is
-    taken as the largest epoch's rows."""
+    the parity gradient arrives in time.  outputs: K, the head's output
+    columns.  The block the epochs read is taken as the largest epoch's
+    rows."""
+    k = float(outputs)
     rows = float(np.sum(row_counts))
-    sys_flops = 4.0 * d * rows
-    sys_bytes = ITEM * (d + 1.0) * rows
-    block = ITEM * (d + 1.0) * float(np.max(row_counts, initial=0.0))
+    sys_flops = 4.0 * d * k * rows
+    sys_bytes = ITEM * (d + k) * rows
+    block = ITEM * (d + k) * float(np.max(row_counts, initial=0.0))
     if parity_rows <= 0 or parity_ok is None:
         return Work(sys_flops, sys_bytes, 0.0, 0.0, block)
     hits = float(np.sum(parity_ok))
     c = float(parity_rows)
-    par_flops = hits * min(4.0 * c * d, 2.0 * d * d)
-    par_bytes = hits * ITEM * min(c * (d + 1.0), d * (d + 1.0))
+    par_flops = hits * min(4.0 * c * d * k, 2.0 * d * d * k)
+    par_bytes = hits * ITEM * min(c * (d + k), d * (d + k))
     return Work(sys_flops, sys_bytes, par_flops, par_bytes, block)
 
 

@@ -4,9 +4,13 @@
         --trace 0
 
 Everything about a cell is found by name from `BENCHMARK.json`: its
-configuration in `chipbench/configs/<config>.json`, its traffic in
+configuration in `chipbench/configs/<config>.json`, whose parts are
+found by the names it gives them (`deploy.py`): its data builder in
+`chipbench/datasets/<data.kind>.py`, its fleet in
+`chipbench/fleets/<fleet.kind>.py` and its session reference in
+`chipbench/reference/<reference>.py`; its traffic in
 `chipbench/traffic/<traffic>.json` (which names its driver,
-`chipbench/traffic/<driver>.py`), and each metric's reader in
+`chipbench/traffic/<driver>.py`); and each metric's reader in
 `chipbench/metrics/<metric>.py` (or `<metric before its first dot>.py`).
 
 The run refuses to start without a TPU, or with fewer chips than the cell
@@ -29,7 +33,6 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import glob  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -44,6 +47,8 @@ for _p in (HERE, os.path.join(ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+from deploy import load_module  # noqa: E402
+
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # the benchmark's host spans (`Ctx.span`) a traced run reads back
 SPANS = ("window", "session", "plan", "run")
@@ -52,14 +57,6 @@ SPANS = ("window", "session", "plan", "run")
 def load_json(*parts: str) -> Any:
     with open(os.path.join(*parts)) as f:
         return json.load(f)
-
-
-def load_module(path: str):
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_" + os.path.basename(path)[:-3].replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def find_reader(name: str):
@@ -232,8 +229,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     peaks = load_json(HERE, "peaks.json").get(kind)
     if require_tpu and peaks is None:
         raise KeyError(f"no peaks for device kind {kind!r} in peaks.json")
-    ctx = Ctx(system=build(cfg, seed), traffic=traffic, seed=seed,
-              chips=cell["chips"], peaks=peaks,
+    ctx = Ctx(system=build(cfg, seed, traffic.get("fleet_seed")),
+              traffic=traffic, seed=seed, chips=cell["chips"], peaks=peaks,
               driver=load_module(os.path.join(
                   HERE, "traffic", traffic["driver"] + ".py")))
     ctx.driver.warm(ctx)
@@ -260,12 +257,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
+    t_ref = time.perf_counter()
     answers = [checks.program_answer(*kept) for kept in ctx.answers]
     ctx.answers = []
     numbers = [checks.compare(ctx.system, a) for a in answers]
     verdict, ok = checks.verdict(cfg, numbers)
     ok = ok and failed == 0
-    print(f"answers compared: {len(numbers)}", file=sys.stderr)
+    print(f"answers compared: {len(numbers)} in "
+          f"{time.perf_counter() - t_ref:.1f} s", file=sys.stderr)
     device = {"platform": jax.devices()[0].platform, "kind": kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     result = {"correct": bool(ok), "attempted": lanes, "failed": failed,

@@ -1,12 +1,16 @@
 """`BENCHMARK.json` is whole: every name it uses resolves to a file of the
 benchmark, and every per-layer metric's `moves` is reported by each cell
-it lists."""
+it lists; every configuration file names parts that exist and expose the
+interface the harness calls."""
+import glob
+import inspect
 import json
 import os
 import re
 
 import pytest
 
+import deploy
 import run
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -73,3 +77,30 @@ def test_bounds(bench):
         assert 0.01 <= m["bound"] <= 0.25, m["name"]
     setup = next(m for m in bench["end_to_end"] if m["name"] == "setup_s")
     assert setup["bound"] == 0.25
+
+
+# (folder, the configuration's name for it, function: its parameters)
+PARTS = [
+    ("datasets", lambda cfg: cfg["data"]["kind"],
+     {"build": ["spec", "seed"]}),
+    ("fleets", lambda cfg: cfg["fleet"]["kind"],
+     {"build": ["spec", "data_spec", "seed"]}),
+    ("reference", lambda cfg: cfg["reference"],
+     {"answer": ["system", "name", "key", "rng", "overrides", "ar",
+                 "t_star"],
+      "root": ["system", "spec"],
+      "work": ["system", "name", "rng", "plan"]}),
+]
+
+
+@pytest.mark.parametrize("folder, name_of, interface", PARTS,
+                         ids=[p[0] for p in PARTS])
+@pytest.mark.parametrize("path", sorted(glob.glob(
+    os.path.join(BENCH_DIR, "configs", "*.json"))), ids=os.path.basename)
+def test_every_configuration_names_its_parts(path, folder, name_of,
+                                             interface):
+    with open(path) as f:
+        mod = deploy.part(folder, name_of(json.load(f)))
+    for fn, params in interface.items():
+        assert list(inspect.signature(getattr(mod, fn)).parameters) \
+            == params, (folder, fn)

@@ -2,18 +2,21 @@
 
 Traffic parameters (the traffic file):
   strategy      the configuration's strategy name every lane runs
+  fleet_seed    the seed the fleet is drawn from under every `--seed`:
+                a sweep plans one fleet, and the fleet sets each lane's
+                loads and so the shape buckets its lanes fall into
   fixed_c       one parity budget per lane (the sweep's deltas times m)
   lanes         how many lanes a call has (a failed call counts them all)
   warm_rounds   sweeps run in set-up (the first compiles every bucket)
   check         {"blocks": b, "within_first": j}: one of the first j
                 sweeps, drawn from the seed, has one lane compared in each
-                of b equal blocks of its lanes; the lane mesh puts each
-                block of a shape bucket on one device, so with b = 2 x
-                devices every device of both buckets is looked at
+                of b equal blocks of its lanes (the lane mesh splits each
+                shape bucket's lanes over devices in contiguous blocks)
   trace_seconds how long a `--trace 1` run traces
 
 Each sweep draws a new key and delay generator per lane from `--seed`, at
-the same shapes, so nothing compiles in the window.
+the same shapes, so nothing compiles in the window; the data, too, comes
+from `--seed`.
 """
 from __future__ import annotations
 
