@@ -13,14 +13,20 @@ Since the sweep-engine refactor the scan body lives in a PURE BATCHED CORE:
 a solo `Session.run` is a size-1 batch of the same compiled computation
 that `run_sweep` uses to execute a whole sweep of sessions at once.  Lanes
 (sessions) are grouped into shape buckets — same strategy static structure,
-same operand shapes — and each bucket compiles ONE engine: a
-`jax.lax.map` over the per-device lanes inside a `shard_map` over the lane
-mesh (`repro.launch.mesh.make_lane_mesh`).  Every lane therefore executes
-the exact same unbatched per-lane program whether it runs alone or in a
-64-lane sweep, which is what makes the per-lane traces bit-for-bit equal
-to solo runs (`tests/test_run_sweep.py`) — a `vmap` over lanes would not
-be: XLA:CPU's batched/gemm lowerings change last-ulp results with the
-batch size.
+same operand shapes.  One lane scheduler (`_execute_lanes`) then splits
+each bucket of B lanes on D devices into chunks the device count divides
+(`repro.launch.mesh.place_lanes`): `(B // D) * D` lanes on all D devices,
+and the `B % D` left over one a device on the least-loaded devices, a
+lane weighing the rows of its X operand.  Each chunk is one compiled
+engine on its own 1-d lane mesh: a `jax.lax.map` over the per-device
+lanes inside a `shard_map`.  Every chunk is staged on its devices and
+dispatched before any output is fetched, so chunks on disjoint devices
+run at once; a solo run is one chunk on the first device.  Every lane
+executes the exact same unbatched per-lane program whether it runs alone
+or in a 64-lane sweep, on whichever device, which is what makes the
+per-lane traces bit-for-bit equal to solo runs (`tests/test_run_sweep.py`)
+— a `vmap` over lanes would not be: XLA:CPU's batched/gemm lowerings
+change last-ulp results with the batch size.
 
 Lifecycle:
 
@@ -32,15 +38,15 @@ Lifecycle:
     report  = session.run(data)          # -> TraceReport
 
     # a whole sweep: one batched planning solve + one compiled engine
-    # per shape bucket, sharded over the device mesh
+    # per chunk of a shape bucket, spread over the devices
     reports = run_sweep([session_a, session_b, ...], data)
 
 Compiled engines are cached at MODULE level, keyed by the strategy's full
 static structure (every primitive dataclass field that could steer the
-trace, not just `engine_key`) plus the operand shapes and the lane count —
-so sweeps, re-runs, and sessions cloned via `dataclasses.replace` share
-compiled engines exactly when their traced computation is identical, and
-never otherwise.
+trace, not just `engine_key`) plus the operand shapes, the lane count and
+the chunk's devices — so sweeps, re-runs, and sessions cloned via
+`dataclasses.replace` share compiled engines exactly when their traced
+computation is identical, and never otherwise.
 """
 from __future__ import annotations
 
@@ -53,7 +59,7 @@ from typing import (TYPE_CHECKING, Any, Callable, Dict, Hashable, List,
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.core import aggregation
@@ -65,8 +71,8 @@ if TYPE_CHECKING:  # annotation-only: keeps the api layer free of sim imports
     from repro.sim.network import FleetSpec
 
 # Compiled sweep engines, shared by every Session in the process: one entry
-# per (strategy static structure, operand shapes, lane count).  A 16-lane
-# delta sweep compiles once per shape bucket instead of once per Session,
+# per (strategy static structure, operand shapes, lane count, devices).  A
+# 16-lane delta sweep compiles once per chunk instead of once per Session,
 # and solo re-runs of equivalent sessions never retrace.  Each engine's
 # closure pins its bucket's first strategy state (which can hold MB-scale
 # parity arrays), so the cache is a BOUNDED LRU: least-recently-used
@@ -187,8 +193,9 @@ def make_epoch_step(strategy: Strategy, state: Any, m: int) -> Callable:
 
 
 def _build_engine(strategy: Strategy, state: Any, data: TrainData,
-                  shared: Dict[str, jax.Array], args: tuple) -> Callable:
-    """Compile the batched engine for one shape bucket.
+                  mesh: jax.sharding.Mesh, shared: Dict[str, jax.Array],
+                  args: tuple) -> Callable:
+    """Compile the batched engine for one chunk of a shape bucket.
 
     `shared` holds the lane-invariant device operands (the strategy's
     declared `data_device_keys` plus `beta_true`), replicated across the
@@ -196,16 +203,15 @@ def _build_engine(strategy: Strategy, state: Any, data: TrainData,
     of the operand bytes and every lane reads the same ones.  `args` =
     (dev_lanes, arrivals, lr), every leaf stacked on a leading lane axis
     of size B.  The per-lane program is the classic solo scan engine;
-    lanes are split over the lane mesh by `shard_map` and iterated per
-    device with `jax.lax.map`, so each lane's arithmetic is identical at
-    every B (the bit-for-bit guarantee — see module docstring).
+    lanes are split over the chunk's lane mesh by `shard_map` and
+    iterated per device with `jax.lax.map`, so each lane's arithmetic is
+    identical at every B (the bit-for-bit guarantee — see module
+    docstring).
     """
-    from repro.launch.mesh import make_lane_mesh
     from repro.launch.sharding import lane_specs
 
     d, dtype = data.model_dim, data.xs.dtype
     n_lanes = jax.tree.leaves(args)[0].shape[0]
-    mesh = make_lane_mesh(n_lanes)
     epoch_step = make_epoch_step(strategy, state, data.m)
 
     def lanes(shared_op, *lane_args):
@@ -235,21 +241,72 @@ def _build_engine(strategy: Strategy, state: Any, data: TrainData,
                        in_specs=(replicated,) + tuple(
                            lane_specs(a) for a in args),
                        out_specs=(P("lanes"), P("lanes")))
-    # compiled ahead of the call for this bucket's exact shapes, so the
+    # compiled ahead of the call for this chunk's exact shapes, so the
     # engine's program text (`as_text()`) can be inspected
     with obs.span("repro.build", lanes=n_lanes):
         return jax.jit(fn).lower(shared, *args).compile()
+
+
+def _lane_weight(dev: Dict[str, jax.Array]) -> int:
+    """What a lane costs its device: the rows of its X operand (packed
+    `sys_x`, else the full `x`), or 1 where it has neither."""
+    for k in ("sys_x", "x"):
+        if k in dev:
+            return int(dev[k].shape[0])
+    return 1
+
+
+def _on_chunk(replica: jax.Array, mesh: jax.sharding.Mesh) -> jax.Array:
+    """`replica`'s shards on `mesh`'s devices, as one array replicated
+    over `mesh`: a chunk's copy of a shared operand, made without copying
+    it again."""
+    shards = {s.device: s.data for s in replica.addressable_shards}
+    return jax.make_array_from_single_device_arrays(
+        replica.shape, NamedSharding(mesh, P()),
+        [shards[d] for d in mesh.devices.flat])
+
+
+def _stack_lanes(lanes: Sequence[Any], mesh: jax.sharding.Mesh) -> Any:
+    """Stack per-lane pytrees leafwise on a leading lane axis split over
+    `mesh` in contiguous blocks.  Host (NumPy) leaves are stacked on the
+    host; device leaves are copied to their block's device and stacked
+    there, so no device computes for another device's block: a device
+    busy with an earlier chunk delays only its own."""
+    def stack_on_host(*xs):
+        return np.stack(xs) if isinstance(xs[0], np.ndarray) else list(xs)
+
+    devices = list(mesh.devices.flat)
+    q = len(lanes) // len(devices)
+    blocks = []
+    for j, device in enumerate(devices):
+        block = jax.device_put(
+            jax.tree.map(stack_on_host, *lanes[j * q:(j + 1) * q]), device)
+        blocks.append(jax.tree.map(
+            lambda x: jnp.stack(x) if isinstance(x, list) else x, block,
+            is_leaf=lambda x: isinstance(x, list)))
+
+    def assemble(*parts):
+        spec = P("lanes", *([None] * (parts[0].ndim - 1)))
+        return jax.make_array_from_single_device_arrays(
+            (len(lanes),) + parts[0].shape[1:], NamedSharding(mesh, spec),
+            list(parts))
+
+    return jax.tree.map(assemble, *blocks)
 
 
 def _execute_lanes(entries: Sequence[tuple],
                    data: TrainData) -> List[tuple]:
     """Run every (session, state, schedule) lane through the batched core.
 
-    Lanes are grouped into shape buckets; each bucket stacks its operands,
-    fetches (or compiles) its engine from the module cache and executes
-    all its lanes in one sharded call.  Returns each lane's
-    ((epochs+1,) NMSE trace, (model_dim,) final beta), in order.
+    Lanes are grouped into shape buckets, and `launch.mesh.place_lanes`
+    splits the buckets into chunks and places them on the devices by
+    load.  Every chunk is staged on its own devices and dispatched
+    before any output is fetched, so chunks on disjoint devices run at
+    once.  Returns each lane's ((epochs+1,) NMSE trace, (model_dim,)
+    final beta), in order.
     """
+    from repro.launch.mesh import make_lane_mesh, place_lanes
+
     devs: List[Dict[str, jax.Array]] = []
     arrs: List[Dict[str, np.ndarray]] = []
     buckets: Dict[Hashable, List[int]] = {}
@@ -261,42 +318,66 @@ def _execute_lanes(entries: Sequence[tuple],
             arrs.append(arr)
             key = _bucket_key(sess.strategy, state, data, dev, arr)
             buckets.setdefault(key, []).append(i)
+        keys = list(buckets)
+        chunks = place_lanes(
+            [(len(buckets[k]), _lane_weight(devs[buckets[k][0]]))
+             for k in keys], len(jax.devices()))
+        obs.count("lane_buckets_split", len(chunks) - len(
+            {b for b, _, _ in chunks}))
+        # operands the strategy declares as pure functions of `data` are
+        # lane-invariant within one call: ONE copy a device, replicated
+        # once over every device a chunk of its bucket runs on, and
+        # stack only the genuinely per-lane state
+        shared: List[Dict[str, jax.Array]] = []
+        for k in keys:
+            sess0 = entries[buckets[k][0]][0]
+            dev0 = devs[buckets[k][0]]
+            data_keys = set(getattr(sess0.strategy, "data_device_keys",
+                                    ())) & set(dev0)
+            shared.append({**{n: dev0[n] for n in data_keys},
+                           "beta_true": data.beta_true})
+        need: Dict[int, tuple] = {}
+        for b, _, ids in chunks:
+            for v in shared[b].values():
+                need.setdefault(id(v), (v, set()))[1].update(ids)
+        replicas = {i: jax.device_put(v, NamedSharding(
+                        make_lane_mesh(len(on), sorted(on)), P()))
+                    for i, (v, on) in need.items()}
 
     dtype = data.xs.dtype
-    results: List[Optional[tuple]] = [None] * len(entries)
-    for key, idxs in buckets.items():
-        b = len(idxs)
+    outputs = []
+    for b, positions, ids in chunks:
+        idxs = [buckets[keys[b]][p] for p in positions]
+        n = len(idxs)
         sess0, state0, _ = entries[idxs[0]]
-        with obs.span("repro.stage", lanes=b):
-            # operands the strategy declares as pure functions of `data`
-            # are lane-invariant within one call: pass ONE copy,
-            # replicated, and stack only the genuinely per-lane state
-            data_keys = set(getattr(sess0.strategy, "data_device_keys",
-                                    ())) & set(devs[idxs[0]])
-            shared = {k: devs[idxs[0]][k] for k in data_keys}
-            shared["beta_true"] = data.beta_true
-            dev_b = {k: jnp.stack([devs[i][k] for i in idxs])
-                     for k in devs[idxs[0]] if k not in data_keys}
-            arr_b = {k: jnp.asarray(np.stack([arrs[i][k] for i in idxs]))
-                     for k in arrs[idxs[0]]}
-            lr_b = jnp.asarray(
-                np.asarray([entries[i][0].lr for i in idxs]), dtype=dtype)
-            args = (dev_b, arr_b, lr_b)
+        with obs.span("repro.stage", lanes=n):
+            mesh = make_lane_mesh(n, ids)
+            op = {name: _on_chunk(replicas[id(v)], mesh)
+                  for name, v in shared[b].items()}
+            args = _stack_lanes(
+                [({name: v for name, v in devs[i].items()
+                   if name not in shared[b]}, arrs[i],
+                  np.asarray(entries[i][0].lr, dtype=dtype))
+                 for i in idxs], mesh)
 
-        engine_key = (key, b)
-        with obs.span("repro.engine", lanes=b,
+        engine_key = (keys[b], n, ids)
+        with obs.span("repro.engine", lanes=n, devices=len(ids),
                       builds=int(engine_key not in _ENGINE_CACHE)):
             engine = cache_engine(
                 engine_key,
-                lambda: _build_engine(sess0.strategy, state0, data, shared,
-                                      args))
-            out_trace, out_beta = engine(shared, *args)
-        with obs.span("repro.fetch", lanes=b):
-            out_trace, out_beta = np.asarray(out_trace), np.asarray(out_beta)
-        for j, i in enumerate(idxs):
-            results[i] = (out_trace[j], out_beta[j])
+                lambda: _build_engine(sess0.strategy, state0, data, mesh,
+                                      op, args))
+            outputs.append((idxs, engine(op, *args)))
+        for i in idxs:
             # per-session mirror: introspection + lifetime of the session
             entries[i][0]._engines[engine_key] = engine
+
+    results: List[Optional[tuple]] = [None] * len(entries)
+    with obs.span("repro.fetch", lanes=len(entries)):
+        for idxs, (out_trace, out_beta) in outputs:
+            out_trace, out_beta = np.asarray(out_trace), np.asarray(out_beta)
+            for j, i in enumerate(idxs):
+                results[i] = (out_trace[j], out_beta[j])
     return results  # type: ignore[return-value]
 
 

@@ -19,7 +19,9 @@ would fire once, at trace time -- and they never wait for the device.
 
 Counters are process-wide counts that no single call explains:
 `engine_builds` (compiled engines built by the shared engine cache),
-`engine_evictions` (engines the cache dropped at its cap), and
+`engine_evictions` (engines the cache dropped at its cap),
+`lane_buckets_split` (shape buckets the sweep engine ran as more than
+one chunk, `launch.mesh.place_lanes`), and
 `sample_groups_uniform` / `sample_groups_mixed` (device groups whose
 epoch delays `core.delay_model.sample_epoch_totals` drew with one
 success probability / with one per device).  `counters()` returns a copy.
@@ -47,6 +49,7 @@ SPANS = (
 _NAMES = frozenset(SPANS)
 
 _COUNTS: Dict[str, int] = {"engine_builds": 0, "engine_evictions": 0,
+                           "lane_buckets_split": 0,
                            "sample_groups_uniform": 0,
                            "sample_groups_mixed": 0}
 

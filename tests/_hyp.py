@@ -10,8 +10,8 @@ a ModuleNotFoundError.  Importing from this module instead gives you:
     over a fixed-seed sample of the declared strategy space.
 
 Only the tiny strategy surface this repo uses is emulated: `integers`,
-`floats`, `sampled_from`, keyword-style `@given`, and `@settings` with
-`max_examples` / `deadline`.
+`floats`, `lists`, `sampled_from`, keyword-style `@given`, and `@settings`
+with `max_examples` / `deadline`.
 """
 from __future__ import annotations
 
@@ -41,6 +41,12 @@ except ImportError:  # deterministic stand-in
         @staticmethod
         def floats(min_value, max_value):
             return _Strategy(lambda r: r.uniform(min_value, max_value))
+
+        @staticmethod
+        def lists(elements, min_size=0, max_size=8):
+            return _Strategy(lambda r: [
+                elements.draw(r)
+                for _ in range(r.randint(min_size, max_size))])
 
         @staticmethod
         def sampled_from(elements):

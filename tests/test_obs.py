@@ -92,7 +92,7 @@ def test_solo_session_spans_nest_by_layer(tmp_path, small):
     assert run[3] == {"lanes": 1}
     assert _named(found, "repro.encode")[0][3]["c"] == int(0.3 * data.m)
     engine, = _named(found, "repro.engine")
-    assert engine[3]["lanes"] == 1
+    assert engine[3]["lanes"] == 1 and engine[3]["devices"] == 1
     # an engine compiled in this call shows as a build inside its span
     assert engine[3]["builds"] == len(_named(found, "repro.build"))
 
@@ -112,6 +112,9 @@ def test_sweep_spans_count_lanes(tmp_path, small):
     assert sample[3] == {"lanes": 3, "epochs": EPOCHS}
     assert _named(found, "repro.report")[0][3] == {"lanes": 3}
     assert sum(s[3]["lanes"] for s in _named(found, "repro.fetch")) == 3
+    # every chunk is dispatched before the one fetch of all outputs
+    fetch, = _named(found, "repro.fetch")
+    assert all(e[2] <= fetch[1] for e in _named(found, "repro.engine"))
     assert {s[0] for s in found} <= set(obs.SPANS)
 
 
@@ -158,6 +161,8 @@ def test_engine_cache_counters(monkeypatch, small):
         after = obs.counters()
         assert after["engine_builds"] - before["engine_builds"] == 3
         assert after["engine_evictions"] - before["engine_evictions"] == 2
+        # one lane, one chunk: nothing split
+        assert after["lane_buckets_split"] == before["lane_buckets_split"]
         assert len(_ENGINE_CACHE) == 1
     finally:
         _ENGINE_CACHE.clear()

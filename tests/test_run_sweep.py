@@ -8,6 +8,10 @@ no batched lowering can perturb last-ulp arithmetic).  Every comparison
 here is exact (`assert_array_equal`), not approximate.
 """
 import dataclasses
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -244,6 +248,131 @@ def test_lane_mesh_size_divides(n_lanes):
     k = lane_mesh_size(n_lanes)
     assert 1 <= k <= max(1, len(jax.devices()))
     assert n_lanes % k == 0
+
+
+def _place(counts, weights, n_devices):
+    from repro.launch.mesh import place_lanes
+    return place_lanes(list(zip(counts, weights)), n_devices)
+
+
+def _per_device(chunks, n_devices):
+    lanes = [0] * n_devices
+    for _, positions, ids in chunks:
+        for d in ids:
+            lanes[d] += len(positions) // len(ids)
+    return lanes
+
+
+_BUCKETS = dict(counts=st.lists(st.integers(1, 20), min_size=1, max_size=6),
+                n_devices=st.integers(1, 8))
+
+
+@settings(max_examples=40, deadline=None)
+@given(weight=st.sampled_from([1, 512, 7200]), **_BUCKETS)
+def test_place_lanes_spreads_equal_lanes(counts, n_devices, weight):
+    """Every lane placed once, each chunk a multiple of its devices, a
+    bucket the devices divide one chunk on all of them, and with equal
+    weights no device more than one lane ahead of another."""
+    chunks = _place(counts, [weight] * len(counts), n_devices)
+    for b, n in enumerate(counts):
+        mine = [c for c in chunks if c[0] == b]
+        placed = [p for _, positions, _ in mine for p in positions]
+        assert sorted(placed) == list(range(n))
+        if n % n_devices == 0:
+            assert mine == [(b, tuple(range(n)), tuple(range(n_devices)))]
+    for _, positions, ids in chunks:
+        assert len(positions) % len(ids) == 0
+        assert list(ids) == sorted(set(ids))
+        assert all(0 <= d < n_devices for d in ids)
+    lanes = _per_device(chunks, n_devices)
+    assert max(lanes) - min(lanes) <= 1
+    assert sum(lanes) == sum(counts)
+
+
+@settings(max_examples=40, deadline=None)
+@given(weights=st.lists(st.integers(1, 8000), min_size=6, max_size=6),
+       **_BUCKETS)
+def test_place_lanes_is_deterministic(counts, n_devices, weights):
+    weights = weights[:len(counts)]
+    chunks = _place(counts, weights, n_devices)
+    assert chunks == _place(counts, weights, n_devices)
+    assert sorted(p for c in chunks for p in c[1]) == sorted(
+        p for n in counts for p in range(n))
+    one = _place(counts, weights, 1)
+    assert sorted(one) == [(b, tuple(range(n)), (0,))
+                           for b, n in enumerate(counts)]
+
+
+def test_place_lanes_sec4_fleet():
+    """The §IV fleet's buckets (8 full-X lanes, 7 packed to 5,632 rows,
+    1 packed to 5,120) on four devices: four lanes a device, the 7-lane
+    bucket split 4 + 3 and the single lane on the fourth device."""
+    chunks = _place([8, 7, 1], [7200, 5632, 5120], 4)
+    assert chunks == [(0, tuple(range(8)), (0, 1, 2, 3)),
+                      (1, (0, 1, 2, 3), (0, 1, 2, 3)),
+                      (1, (4, 5, 6), (0, 1, 2)),
+                      (2, (0,), (3,))]
+    assert _per_device(chunks, 4) == [4, 4, 4, 4]
+
+
+def test_place_lanes_rejects_empty_input():
+    from repro.launch.mesh import place_lanes
+    with pytest.raises(ValueError, match="n_devices"):
+        place_lanes([(3, 1.0)], 0)
+    with pytest.raises(ValueError, match="lane"):
+        place_lanes([(0, 1.0)], 2)
+    assert place_lanes([], 4) == []
+
+
+def _split_sweep_check():
+    """Run in a process with four host devices: a sweep whose buckets
+    the devices do not divide (6 full-X lanes, 5 packed, 1 at c = 0)
+    runs each chunk where `place_lanes` put it, counts two split
+    buckets, and every lane stays bit-equal to its solo run."""
+    from repro import obs
+
+    assert len(jax.devices()) == 4
+    fleet = paper_fleet(0.2, 0.2, seed=1, n=12, d=40)
+    data = TrainData.linreg(jax.random.PRNGKey(0), n=12, ell=60, d=40)
+    cs = [48, 72, 96, 120, 144, 168, 240, 264, 288, 312, 336, 0]
+    sessions = [Session(strategy=make_strategy("cfl", key_seed=7 + j,
+                                               fixed_c=c),
+                        fleet=fleet, lr=LR, epochs=10, seed=j)
+                for j, c in enumerate(cs)]
+    states = plan_sweep(sessions, data)
+    before = obs.counters()["lane_buckets_split"]
+    reports = run_sweep(sessions, data, states=states)
+    assert obs.counters()["lane_buckets_split"] - before == 2
+    planned = [(0, 1, 2, 3)] * 4 + [(0, 1)] * 2 + [(0, 1, 2, 3)] * 4 \
+        + [(2,), (3,)]
+    for sess, ids in zip(sessions, planned):
+        (key, engine), = sess._engines.items()
+        assert key[2] == ids
+        for sh in jax.tree.leaves(engine.output_shardings):
+            assert sh.device_set == {jax.devices()[d] for d in ids}
+    for rep, sess, state in zip(reports, sessions, states):
+        solo = sess.run(data, rng=np.random.default_rng(sess.seed),
+                        state=state)
+        np.testing.assert_array_equal(rep.nmse, solo.nmse)
+        np.testing.assert_array_equal(rep.beta, solo.beta)
+        np.testing.assert_array_equal(rep.times, solo.times)
+    assert obs.counters()["lane_buckets_split"] - before == 2
+
+
+def test_split_buckets_on_four_devices_bit_equal_solo():
+    """`_split_sweep_check` in a child process, the only place the
+    tier-1 suite sees four devices and so a bucket split over them."""
+    here = Path(__file__).resolve().parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [str(here.parent / "src"), str(here)]),
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") + " --xla_force_"
+                          "host_platform_device_count=4").strip())
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import test_run_sweep as t; t._split_sweep_check()"],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-4000:]
 
 
 def test_lane_specs_layout():

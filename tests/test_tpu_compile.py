@@ -102,7 +102,6 @@ def test_session_engine_compiles_with_kernels(one_chip, topo, monkeypatch):
     on.  The backend probes are steered to "tpu" here, in the test, so
     the engine takes its chip branch."""
     import repro.api.session as session_mod
-    import repro.launch.mesh as mesh_mod
     from repro.api import CodedFL, Session, TrainData
     from repro.fleet import FleetTopology, HierarchicalCFL
     from repro.kernels import common
@@ -113,16 +112,15 @@ def test_session_engine_compiles_with_kernels(one_chip, topo, monkeypatch):
     monkeypatch.setattr(common, "backend", lambda: "tpu")
     monkeypatch.setattr(common, "on_tpu", lambda: True)
     monkeypatch.setattr(rg_ops, "on_tpu", lambda: True)
-    monkeypatch.setattr(mesh_mod, "make_lane_mesh", lambda n: mesh)
 
     built = []
     build = session_mod._build_engine
 
-    def build_for_chip(strategy, state, data, shared, args):
+    def build_for_chip(strategy, state, data, _host_mesh, shared, args):
         def shapes(tree, spec):
             return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
                 a.shape, a.dtype, sharding=NamedSharding(mesh, spec)), tree)
-        built.append(build(strategy, state, data, shapes(shared, P()),
+        built.append(build(strategy, state, data, mesh, shapes(shared, P()),
                            shapes(args, P("lanes"))))
         raise StopIteration
 
