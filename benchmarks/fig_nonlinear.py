@@ -17,9 +17,10 @@ under the MEC delay model.  Three comparisons:
     feature-space head must beat the closed-form least-squares head on
     the RAW inputs (the best any linear model could do), otherwise the
     kernel machinery isn't earning its keep.
-  * **Pallas encode parity** — the feature-space parity encode with
-    `use_kernel=True` (tuned `block="auto"` tiles) must match the XLA
-    path, so the accelerated encode composes with the new strategy.
+  * **Pallas encode parity** — the feature-space parity encode through
+    `encoding.encode_fleet(use_kernel=True)` (tuned `block="auto"`
+    tiles) must match the XLA path on the same features, weights and
+    key, so the accelerated encode composes with the new strategy.
 
     PYTHONPATH=src python -m benchmarks.fig_nonlinear [--epochs 600]
     PYTHONPATH=src python -m benchmarks.fig_nonlinear --smoke   # CI gate
@@ -148,11 +149,17 @@ def run_pair(fleet, data, strategy, epochs: int, seed: int = 0):
 
 def encode_kernel_parity(fleet, data, strategy) -> float:
     """Max |Pallas - XLA| over the feature-space parity encode."""
-    import dataclasses
-    plain = strategy.plan(fleet, data)
-    kern = dataclasses.replace(strategy, use_kernel=True)
-    accel = kern.plan_with(fleet, data, plain.plan)
-    return float(jnp.max(jnp.abs(accel.x_parity - plain.x_parity)))
+    from repro.core import encoding
+    from repro.core.redundancy import systematic_weights
+    state = strategy.plan(fleet, data)
+    weights = jnp.asarray(np.stack(systematic_weights(
+        state.plan, np.full(data.n, data.ell))), dtype=data.xs.dtype)
+    x_par = {}
+    for use_kernel in (False, True):
+        x_par[use_kernel], _ = encoding.encode_fleet(
+            strategy.key, state.features, data.ys, weights, state.c,
+            kind=strategy.generator, use_kernel=use_kernel)
+    return float(jnp.max(jnp.abs(x_par[True] - x_par[False])))
 
 
 # ---------------------------------------------------------------------------

@@ -11,32 +11,19 @@ scan-jitted training trace.  Two sections quantify the replacement:
     `solve_redundancy_batched` call vs 16 sequential legacy solves
     (legacy cost = 16x the measured single solve).
 
-The training section is unchanged: the scan-jitted `Session` engine vs the
-seed's per-epoch Python loop (host-synced every epoch), sharing one
-protocol setup.
-
-  * epoch — the fused round-gradient path (`grad_path="fused"`: packed
-    systematic rows + Gram-folded parity, see `repro.kernels.round_grad`)
-    vs the reference expressions (`grad_path="reference"`), identical
-    Session/plan/schedule otherwise.  Both traces must agree to
-    rtol 1e-3 / atol 1e-6 with bit-identical durations.
+The training section times the scan-jitted `Session` engine on the
+planned state.
 
     PYTHONPATH=src python -m benchmarks.perf_session [--epochs 300]
     PYTHONPATH=src python -m benchmarks.perf_session --smoke   # CI budget
-    PYTHONPATH=src python -m benchmarks.perf_session --smoke --epoch
 
 `--smoke` runs only the new planner (no multi-second legacy baselines) and
 asserts plan latencies stay under fixed budgets, so planner regressions
-fail CI instead of silently eating sweep time.  `--smoke --epoch` runs
-only the epoch section and asserts fused >= $EPOCH_SMOKE_MIN_SPEEDUP
-(default 1.3) x reference epochs/sec on the §IV shapes
-(`BENCH_epoch.json`).
+fail CI instead of silently eating sweep time.
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
-import os
 import time
 
 import jax
@@ -44,8 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import CodedFL, Session, TrainData
-from repro.core import aggregation, cfl
-from repro.core.delay_model import sample_total
+from repro.core import cfl
 from repro.core.encoding import generator_matrix
 from repro.plan import PlanRequest, solve_redundancy_batched
 from repro.sim.network import paper_fleet
@@ -70,31 +56,6 @@ def legacy_encode_fleet(key, xs, ys, weights, c):
 
     xps, yps = jax.vmap(one)(keys, xs, ys, weights)
     return jnp.sum(xps, axis=0), jnp.sum(yps, axis=0)
-
-
-def legacy_epochs_cfl(fleet, state: cfl.CFLState, data: TrainData,
-                      lr: float, epochs: int, rng: np.random.Generator):
-    """The seed repo's per-epoch Python loop (host-synced every epoch)."""
-    xs, ys, beta_true = data.xs, data.ys, data.beta_true
-    n, ell, d = xs.shape
-    m = n * ell
-    plan = state.plan
-    t_star = plan.t_star
-    # one-time parity-upload retransmission draw (part of the legacy
-    # generator stream, drawn before the epoch loop)
-    rng.geometric(1.0 - fleet.edge.p, size=n)
-    beta = jnp.zeros(d, dtype=xs.dtype)
-    errs = [float(aggregation.nmse(beta, beta_true))]
-    for _ in range(epochs):
-        t_i = sample_total(fleet.edge, plan.loads, rng)
-        received = jnp.asarray((t_i <= t_star) & (plan.loads > 0),
-                               dtype=xs.dtype)
-        t_srv = sample_total(fleet.server, np.array([state.c]), rng)[0]
-        par_ok = jnp.asarray(float(t_srv <= t_star), dtype=xs.dtype)
-        g = cfl.epoch_gradient(state, xs, ys, beta, received, par_ok)
-        beta = aggregation.gd_update(beta, g, lr, m)
-        errs.append(float(aggregation.nmse(beta, beta_true)))  # host sync
-    return np.array(errs)
 
 
 def bench_planning(fleet, data: TrainData, session: Session, c: int,
@@ -190,70 +151,8 @@ def bench_planning(fleet, data: TrainData, session: Session, c: int,
     return state
 
 
-def _timed_runs(session: Session, data: TrainData, state, reps: int) -> tuple:
-    """Warm a session's engine, then best-of-`reps` wall time for one
-    full `run` (schedule sampling + scan execution), plus the report."""
-    session.run(data, rng=np.random.default_rng(0), state=state)
-    best, report = np.inf, None
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        report = session.run(data, rng=np.random.default_rng(1), state=state)
-        best = min(best, time.perf_counter() - t0)
-    return best, report
-
-
-def bench_epoch(data: TrainData, session: Session, state: cfl.CFLState,
-                gate: bool, reps: int = 3) -> None:
-    """Fused vs reference round-gradient path, same plan and schedule.
-
-    Times whole `Session.run` calls (epochs/sec as a user sees them, host
-    schedule sampling included) on the §IV CodedFL config.  The two
-    traces are asserted equivalent (rtol 1e-3 / atol 1e-6, durations
-    bit-identical) BEFORE any perf gate, and the artifact is written
-    before the speedup assert so a regression still uploads its numbers.
-    """
-    epochs = session.epochs
-    floor = float(os.environ.get("EPOCH_SMOKE_MIN_SPEEDUP", "1.3"))
-    reference = dataclasses.replace(
-        session,
-        strategy=dataclasses.replace(session.strategy,
-                                     grad_path=aggregation.REFERENCE))
-
-    t_fused, rep_fused = _timed_runs(session, data, state, reps)
-    t_ref, rep_ref = _timed_runs(reference, data, state, reps)
-
-    # correctness first: identical schedules, equivalent trajectories
-    np.testing.assert_array_equal(rep_fused.epoch_durations,
-                                  rep_ref.epoch_durations)
-    np.testing.assert_allclose(rep_fused.nmse, rep_ref.nmse,
-                               rtol=1e-3, atol=1e-6)
-
-    eps_fused = epochs / t_fused
-    eps_ref = epochs / t_ref
-    speedup = eps_fused / eps_ref
-    emit("perf_session/epoch_fused", t_fused * 1e6 / epochs,
-         f"epochs_per_sec={eps_fused:.0f}")
-    emit("perf_session/epoch_reference", t_ref * 1e6 / epochs,
-         f"epochs_per_sec={eps_ref:.0f}")
-    emit("perf_session/epoch_fused_speedup", 0.0,
-         f"fused_over_reference={speedup:.2f}x;floor={floor};"
-         f"epochs={epochs};m={M};d={D}")
-    print(f"epoch: fused {eps_fused:.0f} epochs/s | reference "
-          f"{eps_ref:.0f} epochs/s | speedup {speedup:.2f}x "
-          f"(floor {floor}x)")
-    if gate:
-        dump_bench("epoch", gates={
-            "epoch_fused_epochs_per_sec": round(eps_fused, 1),
-            "epoch_reference_epochs_per_sec": round(eps_ref, 1),
-            "epoch_fused_speedup": round(speedup, 3),
-            "epoch_min_speedup": floor,
-        })
-        assert speedup >= floor, \
-            f"fused epoch path {speedup:.2f}x < required {floor}x"
-
-
-def main(epochs: int = 300, delta: float = 0.28, smoke: bool = False,
-         epoch: bool = False) -> None:
+def main(epochs: int = 300, delta: float = 0.28,
+         smoke: bool = False) -> None:
     fleet = paper_fleet(0.2, 0.2, seed=0)
     data = TrainData.linreg(jax.random.PRNGKey(0), N_DEVICES, ELL, D)
     c = int(delta * M)
@@ -262,50 +161,22 @@ def main(epochs: int = 300, delta: float = 0.28, smoke: bool = False,
                                        include_upload_delay=False),
                       fleet=fleet, lr=LR, epochs=epochs)
 
-    if smoke and epoch:  # epoch-smoke CI stage: fused-vs-reference gate only
-        state = session.plan(data)
-        bench_epoch(data, session, state, gate=True)
-        print("perf_session --smoke --epoch OK (fused floor held)")
-        return
-
     # --- planning section --------------------------------------------------
     state = bench_planning(fleet, data, session, c, smoke)
     if smoke:
         print("perf_session --smoke OK (plan budgets held)")
         return
 
-    # --- training engines (shared setup) -----------------------------------
-    # warmup both paths (jit compilation)
-    session.run(data, rng=np.random.default_rng(0), state=state)
-    legacy_epochs_cfl(fleet, state, data, LR, 5, np.random.default_rng(0))
-
+    # --- training engine (planned state shared) ----------------------------
+    session.run(data, rng=np.random.default_rng(0), state=state)  # compile
     t0 = time.perf_counter()
-    rep = session.run(data, rng=np.random.default_rng(1), state=state)
+    session.run(data, rng=np.random.default_rng(1), state=state)
     t_scan = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    errs = legacy_epochs_cfl(fleet, state, data, LR, epochs,
-                             np.random.default_rng(1))
-    t_loop = time.perf_counter() - t0
-
-    # sanity: both paths compute the same trajectory
-    np.testing.assert_allclose(rep.nmse, errs, rtol=1e-3, atol=1e-6)
-
     eps_scan = epochs / t_scan
-    eps_loop = epochs / t_loop
-    speedup = eps_scan / eps_loop
     emit("perf_session/scan_jitted", t_scan * 1e6 / epochs,
-         f"epochs_per_sec={eps_scan:.0f}")
-    emit("perf_session/legacy_loop", t_loop * 1e6 / epochs,
-         f"epochs_per_sec={eps_loop:.0f}")
-    emit("perf_session/speedup", 0.0,
-         f"scan_over_loop={speedup:.1f}x;epochs={epochs};n={N_DEVICES};d={D}")
-    print(f"\nscan-jitted Session: {eps_scan:.0f} epochs/s | "
-          f"legacy Python loop: {eps_loop:.0f} epochs/s | "
-          f"speedup {speedup:.1f}x")
-
-    # --- fused vs reference round-gradient path (informational here) -------
-    bench_epoch(data, session, state, gate=False)
+         f"epochs_per_sec={eps_scan:.0f};epochs={epochs};n={N_DEVICES};"
+         f"d={D}")
+    print(f"\nscan-jitted Session: {eps_scan:.0f} epochs/s")
 
 
 if __name__ == "__main__":
@@ -314,7 +185,4 @@ if __name__ == "__main__":
     ap.add_argument("--delta", type=float, default=0.28)
     ap.add_argument("--smoke", action="store_true",
                     help="quick CI mode: new planner only, assert budgets")
-    ap.add_argument("--epoch", action="store_true",
-                    help="with --smoke: run only the fused-vs-reference "
-                         "epoch section and gate EPOCH_SMOKE_MIN_SPEEDUP")
     main(**vars(ap.parse_args()))

@@ -19,7 +19,7 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset: fig2,fig3,fig4,fig5,"
                          "schemes,nonlinear,privacy,ablation,noniid,serve,"
-                         "fleet,kernels,epoch,roofline")
+                         "fleet,kernels,roofline")
     args = ap.parse_args(argv)
     only = set(args.only.split(",")) if args.only else None
     from repro.launch.compile_cache import use_compile_cache
@@ -70,10 +70,6 @@ def main(argv=None) -> int:
     if want("kernels"):
         from . import kernels
         kernels.main()
-    if want("epoch"):
-        from . import perf_session
-        # fused-vs-reference round-gradient path (gated in CI epoch-smoke)
-        perf_session.main(epochs=300, smoke=True, epoch=True)
     if want("roofline"):
         from . import roofline_table
         # always prints the coded-kernel attainment section; the dry-run

@@ -11,13 +11,11 @@ wall time, the part of it spent compiling, and the check it passed:
 
   a. device, JAX version, compile cache, tiles the kernels resolve;
   b. planning vs the float64 NumPy oracle, then solo training of
-     UncodedFL and CodedFL on the fused path: finite traces, a 10x NMSE
-     drop, agreement with `grad_path="reference"`, and the Pallas kernel
-     (`tpu_custom_call`) present in the compiled engine;
+     UncodedFL and CodedFL: finite traces, a 10x NMSE drop, and the
+     Pallas kernel (`tpu_custom_call`) present in the compiled engine;
   c. a 4-lane delta sweep, each lane bit-equal to its solo run;
   d. 4 sessions served by `FedServeEngine`, each a prefix of its solo run;
-  e. a 2-tier `HierarchicalCFL(CodedFL)` session (the tier kernel) vs
-     its reference path.
+  e. a 2-tier `HierarchicalCFL(CodedFL)` session (the tier kernel).
 
 `--chips 4` runs only what exists across chips: a 16-lane delta sweep on
 a 4-device lane mesh vs the same lanes run one device at a time, and the
@@ -31,7 +29,6 @@ All phases run in this one process, which holds the chip.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import sys
@@ -61,7 +58,6 @@ C = int(0.28 * M)
 LR = 0.0085
 EPOCHS = 200
 DELTAS = (0.1, 0.2, 0.28, 0.4)
-RTOL, ATOL = 1e-3, 1e-6
 FLEET_N = 10_000
 
 
@@ -110,10 +106,6 @@ class Phase:
 
     def __exit__(self, *exc):
         return False
-
-
-def close(a, b) -> bool:
-    return bool(np.allclose(a, b, rtol=RTOL, atol=ATOL))
 
 
 def same_trace(a, b) -> bool:
@@ -174,30 +166,18 @@ def phase_solo(fleet, data):
     for name, strat, st in (("UncodedFL", UncodedFL(), None),
                             ("CodedFL", coded, state)):
         with Phase(f"b. solo {name}") as ph:
-            fused = Session(strategy=strat, fleet=fleet, lr=LR,
-                            epochs=EPOCHS)
-            rep = fused.run(data, rng=np.random.default_rng(0), state=st)
+            session = Session(strategy=strat, fleet=fleet, lr=LR,
+                              epochs=EPOCHS)
+            rep = session.run(data, rng=np.random.default_rng(0), state=st)
             check(bool(np.all(np.isfinite(rep.nmse))), f"{name}: non-finite")
-            check(any("tpu_custom_call" in t for t in engine_texts(fused)),
+            check(any("tpu_custom_call" in t for t in engine_texts(session)),
                   f"{name}: no tpu_custom_call in the compiled engine")
-            ref_sess = Session(strategy=dataclasses.replace(
-                strat, grad_path="reference"), fleet=fleet, lr=LR,
-                epochs=EPOCHS)
-            ref_rep = ref_sess.run(data, rng=np.random.default_rng(0),
-                                   state=st)
-            check(np.array_equal(rep.epoch_durations,
-                                 ref_rep.epoch_durations),
-                  f"{name}: durations differ from the reference path")
-            err = float(np.max(np.abs(rep.nmse - ref_rep.nmse)))
-            check(close(rep.nmse, ref_rep.nmse),
-                  f"{name}: fused vs reference NMSE max diff {err}")
             drop = rep.nmse[0] / rep.nmse[-1]
             if name == "CodedFL":
                 check(drop >= 10.0, f"CodedFL NMSE fell only {drop:.2f}x")
             ph.done(f"{EPOCHS} epochs finite, NMSE {rep.nmse[0]:.3e} -> "
-                    f"{rep.nmse[-1]:.3e} ({drop:.1f}x), fused vs reference "
-                    f"max |diff| {err:.2e} within rtol {RTOL}/atol {ATOL}, "
-                    "durations equal, tpu_custom_call in engine")
+                    f"{rep.nmse[-1]:.3e} ({drop:.1f}x), "
+                    "tpu_custom_call in engine")
 
 
 def delta_sessions(fleet, deltas):
@@ -243,30 +223,16 @@ def phase_serve(fleet, data):
 
 def phase_tiers(fleet, data):
     with Phase("e. tiers") as ph:
-        topo = FleetTopology.uniform(N, 2)
-        reps, sessions = {}, {}
-        for path in ("fused", "reference"):
-            strat = HierarchicalCFL(base=CodedFL(key=jax.random.PRNGKey(1),
-                                                 fixed_c=C,
-                                                 grad_path=path),
-                                    topology=topo)
-            sessions[path] = Session(strategy=strat, fleet=fleet, lr=LR,
-                                     epochs=EPOCHS)
-            reps[path] = sessions[path].run(data,
-                                            rng=np.random.default_rng(0))
-        fused, ref = reps["fused"], reps["reference"]
-        check(bool(np.all(np.isfinite(fused.nmse))), "tiers: non-finite")
-        check(any("tpu_custom_call" in t
-                  for t in engine_texts(sessions["fused"])),
+        strat = HierarchicalCFL(base=CodedFL(key=jax.random.PRNGKey(1),
+                                             fixed_c=C),
+                                topology=FleetTopology.uniform(N, 2))
+        session = Session(strategy=strat, fleet=fleet, lr=LR, epochs=EPOCHS)
+        rep = session.run(data, rng=np.random.default_rng(0))
+        check(bool(np.all(np.isfinite(rep.nmse))), "tiers: non-finite")
+        check(any("tpu_custom_call" in t for t in engine_texts(session)),
               "tiers: no tpu_custom_call in the compiled engine")
-        check(np.array_equal(fused.epoch_durations, ref.epoch_durations),
-              "tiers: durations differ from the reference path")
-        err = float(np.max(np.abs(fused.nmse - ref.nmse)))
-        check(close(fused.nmse, ref.nmse),
-              f"tiers: fused vs reference NMSE max diff {err}")
-        ph.done(f"2-tier CodedFL NMSE {fused.nmse[0]:.3e} -> "
-                f"{fused.nmse[-1]:.3e}, fused vs reference max |diff| "
-                f"{err:.2e}, durations equal, tpu_custom_call in engine")
+        ph.done(f"2-tier CodedFL NMSE {rep.nmse[0]:.3e} -> "
+                f"{rep.nmse[-1]:.3e}, tpu_custom_call in engine")
 
 
 def phase_four_chips(fleet, data):

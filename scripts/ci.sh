@@ -19,9 +19,6 @@
 #                  per file; scripts/check_docs.py) — subsumes the old
 #                  quickstart smoke
 #   perf-smoke     planner-latency budget gate  -> BENCH_perf.json
-#   epoch-smoke    fused round-gradient path >= 1.3x reference
-#                  epochs/sec on the §IV shapes (floor tunable via
-#                  EPOCH_SMOKE_MIN_SPEEDUP)     -> BENCH_epoch.json
 #   schemes-smoke  scheme sanity + plan budget  -> BENCH_schemes.json
 #   nonlinear-smoke CodedFedL kernel head beats the equal-wall-clock
 #                  uncoded run and the best linear model
@@ -116,7 +113,6 @@ run_stage tests python -m pytest -x -q
 if [[ "$TIER" != "fast" ]]; then
     run_stage docs-check python scripts/check_docs.py
     run_stage perf-smoke python -m benchmarks.perf_session --smoke
-    run_stage epoch-smoke python -m benchmarks.perf_session --smoke --epoch
     run_stage schemes-smoke python -m benchmarks.fig_schemes --smoke
     run_stage nonlinear-smoke python -m benchmarks.fig_nonlinear --smoke
     run_stage privacy-smoke python -m benchmarks.fig_privacy --smoke

@@ -1,7 +1,6 @@
 """Unified training-trace report returned by every `Session` run.
 
-`TraceReport` supersedes the old `sim.simulator.SimResult` (which is now an
-alias of this class).  It is strategy-agnostic: the same fields describe an
+It is strategy-agnostic: the same fields describe an
 uncoded run, a CFL run, or a gradient-coding run, so downstream analysis
 (convergence times, coding gains, comm-load ratios) never branches on which
 strategy produced the trace.

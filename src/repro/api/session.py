@@ -1,13 +1,11 @@
 """`Session` and the batched sweep engine.
 
-One `Session` replaces the three copy-pasted Python epoch loops that used to
-live in `sim.simulator.run_uncoded` / `run_cfl`, `fed.trainer`, and the
-gradient-coding script: the strategy pre-samples every epoch's
-delays/arrivals up front on the host (NumPy, shape `(epochs, n)`), and the
-whole training trace — gradient estimate, GD update, NMSE — executes in one
-jitted `jax.lax.scan`.  The device is synced exactly once per run (to fetch
-the final NMSE trace) instead of once per epoch, which is what dominated
-wall time at small `d`.
+One `Session` runs the epochs of every strategy: the strategy pre-samples
+every epoch's delays/arrivals up front on the host (NumPy, shape
+`(epochs, n)`), and the whole training trace — gradient estimate, GD
+update, NMSE — executes in one jitted `jax.lax.scan`.  The device is
+synced exactly once per run (to fetch the final NMSE trace) instead of
+once per epoch, which is what dominated wall time at small `d`.
 
 Since the sweep-engine refactor the scan body lives in a PURE BATCHED CORE:
 a solo `Session.run` is a size-1 batch of the same compiled computation

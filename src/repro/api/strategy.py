@@ -230,11 +230,7 @@ class UncodedFL:
     """Synchronous uncoded FL: every epoch waits for all n clients (Eq. 2)."""
 
     label: str = "uncoded"
-    grad_path: str = aggregation.FUSED
 
-    # grad_path steers the traced engine; it stays OUT of
-    # engine_value_fields so the engine cache keys on it automatically
-    engine_value_fields: ClassVar[frozenset] = frozenset()
     # the flat training matrices are data-only: one replicated copy per sweep
     data_device_keys: ClassVar[frozenset] = frozenset({"x", "y"})
 
@@ -253,16 +249,14 @@ class UncodedFL:
                 "y": data.ys.reshape(data.m)}
 
     def round_contributions(self, state, dev, beta, arrivals):
-        # exact full gradient (Eq. 2); both grad paths route through the
-        # dispatcher — on CPU they are one and the same expression
+        # exact full gradient (Eq. 2)
         return aggregation.round_gradient(
-            dev["x"], dev["y"], beta,
-            path=aggregation.resolve_grad_path(self.grad_path))
+            dev["x"], dev["y"], beta, path=aggregation.FUSED)
 
     def tiered_contributions(self, state, dev, beta, arrivals, tier_masks):
         return aggregation.tiered_round_gradient(
             dev["x"], dev["y"], beta, None, tier_masks,
-            path=aggregation.resolve_grad_path(self.grad_path)), None
+            path=aggregation.FUSED), None
 
     def uplink_bits(self, state: UncodedState, fleet: "FleetSpec",
                     epochs: int) -> float:
@@ -323,14 +317,10 @@ class CodedFL:
     c_up:       cap on the server's parity budget
     include_upload_delay: charge the one-time parity upload to the clock
     server_always_returns: ablation — parity gradient always lands
-    use_kernel: DEPRECATED — folded into grad_path (True forces "fused");
-                still routes the one-time parity ENCODE through Pallas
+    generator:  distribution of the private generator matrices
     redundancy_plan: pre-solved `RedundancyPlan` (one element of a
                 `repro.plan.solve_redundancy_batched` sweep); `plan` then
                 skips the solve and only encodes
-    grad_path:  "fused" (default — packed one-pass round gradient, Gram
-                parity) or "reference" (the verbatim pre-fusion epoch
-                body, the bit-parity oracle)
     """
 
     key: jax.Array
@@ -338,24 +328,17 @@ class CodedFL:
     c_up: Optional[int] = None
     include_upload_delay: bool = True
     server_always_returns: bool = False
-    use_kernel: bool = False
     generator: str = "normal"
     label: str = "cfl"
     redundancy_plan: Optional["RedundancyPlan"] = None
-    grad_path: str = aggregation.FUSED
-
-    def _grad_path(self) -> str:
-        return aggregation.resolve_grad_path(self.grad_path,
-                                             self.use_kernel)
 
     # knobs that only shape the plan / host-side sampling, never the traced
     # engine: lanes differing in them share one compiled sweep engine
-    # (use_kernel stays keyed — it swaps the parity-gradient code path)
     engine_value_fields: ClassVar[frozenset] = frozenset(
         {"fixed_c", "c_up", "include_upload_delay", "server_always_returns",
          "generator"})
-    # data-only operands (one replicated copy per sweep); the plan-derived
-    # load mask and parity shards stay per-lane
+    # data-only operands of the dense layout (one replicated copy per
+    # sweep); the plan-derived row weights and parity factors stay per-lane
     data_device_keys: ClassVar[frozenset] = frozenset(
         {"x", "y", "row_client"})
 
@@ -377,8 +360,7 @@ class CodedFL:
         """`plan` with the redundancy solve already done (or None to solve)."""
         return cfl.setup(self.key, data.xs, data.ys, fleet.edge, fleet.server,
                          fixed_c=self.fixed_c, c_up=self.c_up,
-                         generator=self.generator, use_kernel=self.use_kernel,
-                         plan=plan)
+                         generator=self.generator, plan=plan)
 
     def sample_epochs(self, state: cfl.CFLState, fleet: "FleetSpec",
                       epochs: int, rng: np.random.Generator) -> EpochSchedule:
@@ -389,56 +371,32 @@ class CodedFL:
 
     def device_state(self, state: cfl.CFLState,
                      data: TrainData) -> Dict[str, jax.Array]:
-        if self._grad_path() == aggregation.FUSED:
-            return cfl.fused_coded_device_state(state, data)
-        return cfl.coded_device_state(state, data)
+        return cfl.fused_coded_device_state(state, data)
 
     def round_contributions(self, state, dev, beta, arrivals):
-        if self._grad_path() == aggregation.FUSED:
-            # fused layout (packed support or dense fallback): the base
-            # row weight carries the load support, parity is Gram-folded
-            x, y, w0, client = aggregation.fused_sys_block(dev)
-            w = w0 * arrivals["received"][client]
-            if state.c == 0:
-                return aggregation.round_gradient(
-                    x, y, beta, w=w, path=aggregation.FUSED)
-            return aggregation.fused_coded_gradient(
-                dev, w, arrivals["parity_ok"], beta)
-        resid = dev["x"] @ beta - dev["y"]
-        # row weight = (point within client's systematic load) AND
-        # (client's partial gradient arrived by t*)
-        w = dev["w_sys"] * arrivals["received"][dev["row_client"]]
-        g_sys = (resid * w) @ dev["x"]
+        # fused layout (packed support or dense fallback): the base row
+        # weight carries the load support, parity is Gram-folded
+        x, y, w0, client = aggregation.fused_sys_block(dev)
+        w = w0 * arrivals["received"][client]
         if state.c == 0:  # delta = 0 degenerates to uncoded FL w/ deadline
-            return g_sys
-        g_par = aggregation.parity_gradient(
-            dev["x_parity"], dev["y_parity"], beta,
-            use_kernel=self.use_kernel)
-        return g_sys + arrivals["parity_ok"] * g_par
+            return aggregation.round_gradient(
+                x, y, beta, w=w, path=aggregation.FUSED)
+        return aggregation.fused_coded_gradient(
+            dev, w, arrivals["parity_ok"], beta)
 
     def tiered_contributions(self, state, dev, beta, arrivals, tier_masks):
         # systematic partials reduce per edge tier; the parity gradient is
         # computed AT the server on the composite parity data, so it rides
         # as the server-side term and bypasses the tier stage entirely
-        if self._grad_path() == aggregation.FUSED:
-            x, y, w0, client = aggregation.fused_sys_block(dev)
-            masks = aggregation.fused_tier_masks(dev, tier_masks)
-            w = w0 * arrivals["received"][client]
-            partials = aggregation.tiered_round_gradient(
-                x, y, beta, w, masks, path=aggregation.FUSED)
-            if state.c == 0:
-                return partials, None
-            g_par = aggregation.gram_parity_gradient(
-                dev["par_gram"], dev["par_gramy"], beta, dev["par_c"])
-            return partials, arrivals["parity_ok"] * g_par
-        resid = dev["x"] @ beta - dev["y"]
-        w = dev["w_sys"] * arrivals["received"][dev["row_client"]]
-        partials = aggregation.tier_reduce(resid * w, dev["x"], tier_masks)
+        x, y, w0, client = aggregation.fused_sys_block(dev)
+        masks = aggregation.fused_tier_masks(dev, tier_masks)
+        w = w0 * arrivals["received"][client]
+        partials = aggregation.tiered_round_gradient(
+            x, y, beta, w, masks, path=aggregation.FUSED)
         if state.c == 0:
             return partials, None
-        g_par = aggregation.parity_gradient(
-            dev["x_parity"], dev["y_parity"], beta,
-            use_kernel=self.use_kernel)
+        g_par = aggregation.gram_parity_gradient(
+            dev["par_gram"], dev["par_gramy"], beta, dev["par_c"])
         return partials, arrivals["parity_ok"] * g_par
 
     def uplink_bits(self, state: cfl.CFLState, fleet: "FleetSpec",
@@ -446,7 +404,7 @@ class CodedFL:
         return cfl.coded_uplink_bits(state, fleet, epochs)
 
     def engine_key(self, state: cfl.CFLState) -> Hashable:
-        return (state.c > 0, self.use_kernel, self._grad_path())
+        return (state.c > 0,)
 
     def sweep_inputs(self, state: cfl.CFLState, fleet: "FleetSpec",
                      epochs: int, rng: np.random.Generator) -> EpochSchedule:
@@ -482,7 +440,6 @@ class GradientCodingFL:
 
     r: int
     label: str = "gradcode"
-    grad_path: str = aggregation.FUSED
 
     # r shapes the plan (groups) only; the traced engine sees it through
     # `engine_key` (n_groups) and the arrival/device tensor shapes
@@ -532,8 +489,7 @@ class GradientCodingFL:
         # reporting this is exactly the full gradient
         w = arrivals["group_ok"][dev["row_group"]]
         return aggregation.round_gradient(
-            dev["x"], dev["y"], beta, w=w,
-            path=aggregation.resolve_grad_path(self.grad_path))
+            dev["x"], dev["y"], beta, w=w, path=aggregation.FUSED)
 
     def tiered_contributions(self, state, dev, beta, arrivals, tier_masks):
         # every contribution is client-resident (the decoded group sums),
@@ -541,7 +497,7 @@ class GradientCodingFL:
         w = arrivals["group_ok"][dev["row_group"]]
         return aggregation.tiered_round_gradient(
             dev["x"], dev["y"], beta, w, tier_masks,
-            path=aggregation.resolve_grad_path(self.grad_path)), None
+            path=aggregation.FUSED), None
 
     def uplink_bits(self, state: GradCodingState, fleet: "FleetSpec",
                     epochs: int) -> float:

@@ -4,16 +4,14 @@ from .delay_model import DeviceDelayParams, compute_cdf, total_cdf, sample_total
 from .returns import expected_return, optimal_loads
 from .redundancy import RedundancyPlan, solve_redundancy, systematic_weights
 from .encoding import ClientParity, generator_matrix, encode_client, encode_fleet
-from .aggregation import (client_partial_gradients, parity_gradient, combine,
-                          uncoded_full_gradient, gd_update, nmse)
-from .cfl import CFLState, setup, epoch_gradient
+from .aggregation import gd_update, nmse
+from .cfl import CFLState, setup
 
 __all__ = [
     "DeviceDelayParams", "compute_cdf", "total_cdf", "sample_total",
     "expected_return", "optimal_loads",
     "RedundancyPlan", "solve_redundancy", "systematic_weights",
     "ClientParity", "generator_matrix", "encode_client", "encode_fleet",
-    "client_partial_gradients", "parity_gradient", "combine",
-    "uncoded_full_gradient", "gd_update", "nmse",
-    "CFLState", "setup", "epoch_gradient",
+    "gd_update", "nmse",
+    "CFLState", "setup",
 ]

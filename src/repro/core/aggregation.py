@@ -15,44 +15,27 @@ traced operand so one compiled step serves every epoch.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
 
 # --------------------------------------------------------------------------
-# The grad_path knob.  Every strategy computes its round gradient through
-# the dispatchers below; `path` picks between
+# The round-gradient dispatchers.  Every strategy computes its round
+# gradient through the functions below with `path=FUSED`: on TPU this
+# launches the `kernels.round_grad` Pallas family (one HBM sweep over X,
+# masks as traced operands).  Off-TPU, where Pallas runs interpreted, the
+# fused win comes from the *operand layout* instead — strategies feed
+# packed systematic rows and Gram-folded parity (see
+# `core.cfl.fused_coded_device_state`) — and the dispatchers evaluate the
+# same sums as jnp expressions.  `path=REFERENCE` always takes the jnp
+# expressions, on any backend.
 #
-#   REFERENCE — the verbatim historical two-pass expressions (the
-#     bit-parity oracle: traces are bit-identical to the pre-fusion
-#     epoch bodies), and
-#   FUSED     — the one-pass hot path.  On TPU this launches the
-#     `kernels.round_grad` Pallas family (one HBM sweep over X, masks
-#     as traced operands).  Off-TPU, where Pallas runs interpreted, the
-#     fused win comes from the *operand layout* instead — strategies
-#     feed packed systematic rows and Gram-folded parity (see
-#     `core.cfl.fused_coded_device_state`) — and the dispatchers keep
-#     the reference jnp expressions, so CPU fused and reference
-#     gradients are bit-identical on identical operands.
-#
-# Dispatch on `path`/backend is host-side at trace time: one compiled
-# engine per path, no runtime branching.
+# Dispatch on `path`/backend is host-side at trace time: no runtime
+# branching.
 # --------------------------------------------------------------------------
 
 FUSED = "fused"
 REFERENCE = "reference"
-GRAD_PATHS = (FUSED, REFERENCE)
-
-
-def resolve_grad_path(path: str, use_kernel: bool = False) -> str:
-    """Validate a strategy's `grad_path`, folding in the deprecated
-    `use_kernel` flag (use_kernel=True forces the fused path)."""
-    if path not in GRAD_PATHS:
-        raise ValueError(
-            f"grad_path must be one of {GRAD_PATHS}, got {path!r}")
-    return FUSED if use_kernel else path
 
 
 def _fused_kernels():
@@ -69,10 +52,9 @@ def round_gradient(x: jax.Array, y: jax.Array, beta: jax.Array,
                    path: str = REFERENCE) -> jax.Array:
     """g = (w * (X beta - y)) @ X — the flat round gradient.
 
-    The reference expression contracts the leading (row-major
-    contiguous) axis both times, exactly as every strategy's epoch body
-    historically wrote it; on TPU the fused path computes the same sum
-    in one HBM pass."""
+    The jnp expression contracts the leading (row-major contiguous) axis
+    both times; on TPU the fused path computes the same sum in one HBM
+    pass."""
     rg_ops = _fused_kernels() if path == FUSED else None
     if rg_ops is not None:
         return rg_ops.masked_round_gradient(x, y, w, beta)
@@ -102,10 +84,10 @@ def tiered_round_gradient(x: jax.Array, y: jax.Array, beta: jax.Array,
                           w: jax.Array | None, tier_masks: jax.Array,
                           path: str = REFERENCE) -> jax.Array:
     """(T, d) tier partials of the masked round gradient — the fleet
-    layer's edge stage.  Reference path: residual once + `tier_reduce`
-    (the pre-fusion expression).  Fused path on TPU: one pass over X
-    shared by all tiers; the per-tier expression matches the flat
-    kernel at T == 1, preserving the single-tier bit-exact contract."""
+    layer's edge stage.  jnp: residual once + `tier_reduce`.  Fused
+    path on TPU: one pass over X shared by all tiers; the per-tier
+    expression matches the flat kernel at T == 1, preserving the
+    single-tier bit-exact contract."""
     rg_ops = _fused_kernels() if path == FUSED else None
     if rg_ops is not None:
         return rg_ops.tier_masked_round_gradient(x, y, w, tier_masks, beta)
@@ -172,51 +154,6 @@ def fused_coded_gradient(dev: dict, w: jax.Array, parity_gate,
     return g_sys + parity_gate * g_par
 
 
-@jax.jit
-def client_partial_gradients(xs: jax.Array, ys: jax.Array,
-                             load_mask: jax.Array, beta: jax.Array) -> jax.Array:
-    """Per-client partial gradients over their systematic loads.
-
-    xs: (n, ell, d), ys: (n, ell)
-    load_mask: (n, ell) 1.0 for the points each client actually processes
-               (its first ell*_i points), 0.0 for punctured points
-    Returns (n, d) per-client partial gradients.
-    """
-    resid = (jnp.einsum("nld,d->nl", xs, beta) - ys) * load_mask
-    return jnp.einsum("nld,nl->nd", xs, resid)
-
-
-@partial(jax.jit, static_argnames=("use_kernel",))
-def parity_gradient(x_par: jax.Array, y_par: jax.Array, beta: jax.Array,
-                    use_kernel: bool = False) -> jax.Array:
-    """(1/c) X~^T (X~ beta - y~)  — the server's redundant gradient (Eq. 18)."""
-    c = x_par.shape[0]
-    if use_kernel:
-        from repro.kernels.coded_grad import ops as cg_ops
-        # block_m="auto" default: row tile from the repro.tune cache
-        g = cg_ops.lsq_gradient(x_par, y_par, beta)
-    else:
-        # (resid @ X) == (X.T @ resid) but contracts the leading (row-major
-        # contiguous) axis — ~6x faster on CPU, bit-identical values
-        g = (x_par @ beta - y_par) @ x_par
-    return g / c
-
-
-@jax.jit
-def combine(partial_grads: jax.Array, received: jax.Array,
-            g_parity: jax.Array, parity_received: jax.Array) -> jax.Array:
-    """Deadline-masked combination of both gradient sources (Eq. 18 + 19).
-
-    partial_grads: (n, d) per-client systematic gradients
-    received: (n,) {0,1} mask — client i's gradient arrived by t*
-    g_parity: (d,) parity gradient
-    parity_received: scalar {0,1} — the server's own parity computation
-                     finished by t* (device n+1 in Eq. 13)
-    """
-    g_sys = jnp.einsum("nd,n->d", partial_grads, received)
-    return g_sys + parity_received * g_parity
-
-
 def tier_reduce(contrib: jax.Array, x: jax.Array,
                 tier_masks: jax.Array) -> jax.Array:
     """Per-tier weighted reduce: (T, m) row masks × (m,) contrib × (m, d) x
@@ -245,18 +182,6 @@ def cross_tier_combine(tier_partials: jax.Array) -> jax.Array:
         return acc + tier_partials[t]
     return jax.lax.fori_loop(1, tier_partials.shape[0], body,
                              tier_partials[0])
-
-
-@jax.jit
-def uncoded_full_gradient(xs: jax.Array, ys: jax.Array, beta: jax.Array) -> jax.Array:
-    """Baseline uncoded FL gradient: every client, every point (Eq. 2).
-
-    Computed over the flattened (m, d) layout: leading-axis contractions
-    lower to fast row-major matvecs (the batched einsum is ~10x slower on
-    CPU for the §IV shapes)."""
-    x = xs.reshape(-1, xs.shape[-1])
-    resid = x @ beta - ys.reshape(-1)
-    return resid @ x
 
 
 @jax.jit

@@ -12,8 +12,10 @@ Ties the pieces together in the order the protocol runs:
      points; the server preemptively computes the parity gradient, waits
      until t*, and combines whatever arrived (Eqs. 18-19).
 
-This module holds protocol state; wall-clock behaviour (sampling T_i,
-deciding who made the deadline) lives in `repro.sim`.
+This module holds protocol state and the scan-engine operand layout;
+wall-clock behaviour (sampling T_i, deciding who made the deadline) lives
+in the strategies' `sample_epochs`, the round itself in their
+`round_contributions` (`repro.api.strategy`).
 """
 from __future__ import annotations
 
@@ -119,7 +121,7 @@ def fused_coded_device_state(state, data, x: jax.Array | None = None,
     Gram factors.  At the paper's §IV operating point this cuts the
     per-epoch row stream ~23% and removes both parity passes entirely.
 
-    The packed keys deliberately do NOT overlap `coded_device_state`'s
+    The packed keys deliberately do NOT overlap the strategies'
     data_device_keys ("x"/"y"/"row_client"): every packed operand is
     plan-derived and must stay per-lane in sweeps.  When the support is
     DENSE (padded count >= PACK_DENSE_FRAC * m) packing is skipped and
@@ -150,8 +152,7 @@ def fused_coded_device_state(state, data, x: jax.Array | None = None,
     idx, valid = packed_row_indices(load_flat)
     row_client = np.repeat(np.arange(n, dtype=np.int32), ell)
     if idx.size >= PACK_DENSE_FRAC * data.m:
-        # dense fallback: full rows, load mask as the base row weight —
-        # bit-identical systematic sums to the reference path
+        # dense fallback: full rows, load mask as the base row weight
         dev = {"x": x, "y": y,
                "row_client": jnp.asarray(row_client),
                "sys_w": jnp.asarray(load_flat, dtype=x.dtype)}
@@ -175,23 +176,6 @@ def fused_coded_device_state(state, data, x: jax.Array | None = None,
             dev["y_parity"] = state.y_parity
     state._fused_dev = (data, x_arg, parity_rows, dev)
     return dev
-
-
-def coded_device_state(state, data) -> dict:
-    """The scan-engine operands every coded scheme shares: flat (m, d)
-    data layout, systematic load mask, per-row client ids, parity shards.
-    `state` needs `.load_mask`/`.x_parity`/`.y_parity`; `data` is a
-    `repro.api.TrainData` (duck-typed — core does not import api).
-    Schemes with extra operands (e.g. LowLatencyCFL's row_chunk) add them
-    on top of this dict."""
-    n, ell = data.n, data.ell
-    row_client = jnp.repeat(jnp.arange(n, dtype=jnp.int32), ell)
-    return {"x": data.xs.reshape(data.m, data.d),
-            "y": data.ys.reshape(data.m),
-            "w_sys": state.load_mask.reshape(data.m),
-            "row_client": row_client,
-            "x_parity": state.x_parity,
-            "y_parity": state.y_parity}
 
 
 @dataclasses.dataclass
@@ -221,7 +205,7 @@ class CFLState:
 def setup(key: jax.Array, xs: jax.Array, ys: jax.Array,
           edge: DeviceDelayParams, server: DeviceDelayParams,
           fixed_c: int | None = None, c_up: int | None = None,
-          generator: str = "normal", use_kernel: bool = False,
+          generator: str = "normal",
           plan: RedundancyPlan | None = None) -> CFLState:
     """Run steps 1-2 of the protocol (optimization + one-time encoding).
 
@@ -244,25 +228,10 @@ def setup(key: jax.Array, xs: jax.Array, ys: jax.Array,
 
     if plan.c > 0:
         x_par, y_par = encoding.encode_fleet(
-            key, xs, ys, weights, plan.c, kind=generator, use_kernel=use_kernel)
+            key, xs, ys, weights, plan.c, kind=generator)
     else:  # delta = 0 degenerates to uncoded FL with deadline t*
         x_par = jnp.zeros((0, xs.shape[-1]), dtype=xs.dtype)
         y_par = jnp.zeros((0,), dtype=xs.dtype)
 
     return CFLState(plan=plan, weights=weights, load_mask=load_mask,
                     x_parity=x_par, y_parity=y_par, edge=edge, server=server)
-
-
-def epoch_gradient(state: CFLState, xs: jax.Array, ys: jax.Array,
-                   beta: jax.Array, received: jax.Array,
-                   parity_received: jax.Array,
-                   use_kernel: bool = False) -> jax.Array:
-    """One epoch's combined gradient estimate given arrival masks."""
-    partials = aggregation.client_partial_gradients(xs, ys, state.load_mask, beta)
-    if state.c > 0:
-        g_par = aggregation.parity_gradient(
-            state.x_parity, state.y_parity, beta, use_kernel=use_kernel)
-    else:
-        g_par = jnp.zeros_like(beta)
-        parity_received = jnp.asarray(0.0, dtype=beta.dtype)
-    return aggregation.combine(partials, received, g_par, parity_received)

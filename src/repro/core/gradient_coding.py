@@ -10,8 +10,8 @@ from ANY n - (r - 1) clients (tolerates s = r - 1 stragglers).
 We implement the "fractional repetition" construction for the common case
 r | n (clients split into n/r groups of r; each group member holds the
 whole group's data and returns the group-sum; the server needs >= 1
-returner per group), plus the wall-clock simulator hook used by the
-`coded_vs_uncoded` ablation benchmark.
+returner per group).  This module holds the plan; the epochs run through
+`repro.api.GradientCodingFL`.
 
 Key contrasts with CFL recorded in EXPERIMENTS.md §Ablation:
   * requires SHARING RAW DATA between clients (privacy cost CFL avoids);
@@ -23,18 +23,8 @@ Key contrasts with CFL recorded in EXPERIMENTS.md §Ablation:
 from __future__ import annotations
 
 import dataclasses
-from typing import TYPE_CHECKING
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from repro.core import aggregation
-from repro.core.delay_model import sample_total
-
-if TYPE_CHECKING:  # annotation-only: core must not import sim/api at runtime
-    from repro.api.report import TraceReport
-    from repro.sim.network import FleetSpec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,44 +43,3 @@ def make_plan(n_clients: int, r: int) -> GradCodingPlan:
                          f"({r} does not divide {n_clients})")
     groups = np.repeat(np.arange(n_clients // r), r)
     return GradCodingPlan(r=r, groups=groups)
-
-
-def group_gradients(xs: jax.Array, ys: jax.Array, beta: jax.Array,
-                    plan: GradCodingPlan) -> jax.Array:
-    """Each group's exact gradient over all its members' data: (n_groups, d)."""
-    per_client = aggregation.client_partial_gradients(
-        xs, ys, jnp.ones(xs.shape[:2], dtype=xs.dtype), beta)   # (n, d)
-    n_groups = int(plan.groups.max()) + 1
-    onehot = jax.nn.one_hot(jnp.asarray(plan.groups), n_groups,
-                            dtype=xs.dtype)                      # (n, g)
-    return jnp.einsum("nd,ng->gd", per_client, onehot)
-
-
-def epoch_time(fleet: FleetSpec, plan: GradCodingPlan, ell: int,
-               rng: np.random.Generator) -> float:
-    """Wall time until every group has >= 1 returner.
-
-    Each client processes r*ell points (it holds its whole group's data);
-    its return time is sampled from the same §II-A delay model.  The epoch
-    ends at max over groups of (min over group members)."""
-    loads = np.full(fleet.edge.n, plan.r * ell)
-    t_i = sample_total(fleet.edge, loads, rng)
-    n_groups = int(plan.groups.max()) + 1
-    per_group = np.full(n_groups, np.inf)
-    for i, g in enumerate(plan.groups):
-        per_group[g] = min(per_group[g], t_i[i])
-    return float(per_group.max())
-
-
-def run_gradient_coding(fleet: FleetSpec, xs, ys, beta_true, lr: float,
-                        epochs: int, rng: np.random.Generator, r: int,
-                        label: str = "gradcode") -> TraceReport:
-    """Wall-clock simulation of fractional-repetition gradient coding.
-
-    Deprecated shim: delegates to the scan-jitted
-    `Session(strategy=GradientCodingFL(r=...))` (see API.md).
-    """
-    from repro.api import GradientCodingFL, Session, TrainData
-    session = Session(strategy=GradientCodingFL(r=r, label=label),
-                      fleet=fleet, lr=lr, epochs=epochs)
-    return session.run(TrainData(xs=xs, ys=ys, beta_true=beta_true), rng=rng)

@@ -59,8 +59,7 @@ def train_coded_head(fleet: FleetSpec, backbone_fn: Optional[Callable],
     reference head, so the NMSE trace measures distance to the kernel
     regressor.
     Returns {"uncoded": TraceReport, "cfl" | "cfedl": TraceReport};
-    the shared `rng` is consumed sequentially (uncoded first), matching
-    the legacy `run_uncoded` + `run_cfl` draw order.
+    the shared `rng` is consumed sequentially (uncoded first).
     """
     feats = extract_features(backbone_fn, xs) if backbone_fn is not None \
         else xs

@@ -15,13 +15,12 @@ import dataclasses
 import jax
 import numpy as np
 
-from repro.core.cfl import (coded_device_state, coded_uplink_bits,
-                            fused_coded_device_state, parity_upload_bits,
-                            sample_parity_upload_time)
+from repro.core.cfl import (coded_uplink_bits, fused_coded_device_state,
+                            parity_upload_bits, sample_parity_upload_time)
 from repro.core.delay_model import DeviceDelayParams
 from repro.core.redundancy import RedundancyPlan
 
-__all__ = ["CodedSchemeState", "coded_device_state", "coded_uplink_bits",
+__all__ = ["CodedSchemeState", "coded_uplink_bits",
            "fused_coded_device_state", "sample_parity_upload_time"]
 
 

@@ -39,7 +39,6 @@ import dataclasses
 from typing import TYPE_CHECKING, ClassVar, Dict, Hashable, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.api.strategy import EpochSchedule, TrainData, coded_epoch_schedule
@@ -82,7 +81,7 @@ class CodedFedL:
     mec_comm:   use the MEC shifted-exponential communication model for
                 the load solve and epoch sampling; None = `d_feat` set
     fixed_c / c_up / include_upload_delay / server_always_returns /
-    use_kernel / generator / redundancy_plan: as in `CodedFL`
+    generator / redundancy_plan: as in `CodedFL`
     """
 
     key: jax.Array
@@ -94,15 +93,9 @@ class CodedFedL:
     c_up: Optional[int] = None
     include_upload_delay: bool = True
     server_always_returns: bool = False
-    use_kernel: bool = False
     generator: str = "normal"
     label: str = "cfedl"
     redundancy_plan: Optional[RedundancyPlan] = None
-    grad_path: str = aggregation.FUSED
-
-    def _grad_path(self) -> str:
-        return aggregation.resolve_grad_path(self.grad_path,
-                                             self.use_kernel)
 
     # knobs that only shape the plan, host-side sampling, or operand
     # VALUES (rff_gamma moves feature values, never shapes); d_feat stays
@@ -155,7 +148,7 @@ class CodedFedL:
         phi = self.features(data)
         st = cfl.setup(self.key, phi, data.ys, fleet.edge, fleet.server,
                        fixed_c=self.fixed_c, c_up=self.c_up,
-                       generator=self.generator, use_kernel=self.use_kernel,
+                       generator=self.generator,
                        plan=plan if plan is not None
                        else self._solve(fleet, data))
         return CodedFedLState(plan=st.plan, load_mask=st.load_mask,
@@ -188,69 +181,38 @@ class CodedFedL:
 
     def device_state(self, state: CodedFedLState,
                      data: TrainData) -> Dict[str, jax.Array]:
-        d_feat = int(state.features.shape[-1])
-        if self._grad_path() == aggregation.FUSED:
-            # packed layout over the FEATURE matrices: kernel-regression
-            # sessions ride the same fused path as raw CFL.  The reshape
-            # is memoized on the state so `fused_coded_device_state`'s
-            # identity-keyed operand cache hits on repeated runs.
-            x_flat = getattr(state, "_features_flat", None)
-            if x_flat is None:
-                x_flat = state.features.reshape(data.m, d_feat)
-                state._features_flat = x_flat
-            return cfl.fused_coded_device_state(state, data, x=x_flat)
-        # `cfl.coded_device_state` with x swapped for the feature tensor
-        # (identical arrays when the map is the identity)
-        n, ell = data.n, data.ell
-        row_client = jnp.repeat(jnp.arange(n, dtype=jnp.int32), ell)
-        return {"x": state.features.reshape(data.m, d_feat),
-                "y": data.ys.reshape(data.m),
-                "w_sys": state.load_mask.reshape(data.m),
-                "row_client": row_client,
-                "x_parity": state.x_parity,
-                "y_parity": state.y_parity}
+        # packed layout over the FEATURE matrices: kernel-regression
+        # sessions ride the same fused path as raw CFL.  The reshape is
+        # memoized on the state so `fused_coded_device_state`'s
+        # identity-keyed operand cache hits on repeated runs.
+        x_flat = getattr(state, "_features_flat", None)
+        if x_flat is None:
+            x_flat = state.features.reshape(data.m,
+                                            int(state.features.shape[-1]))
+            state._features_flat = x_flat
+        return cfl.fused_coded_device_state(state, data, x=x_flat)
 
     def round_contributions(self, state, dev, beta, arrivals):
-        if self._grad_path() == aggregation.FUSED:
-            x, y, w0, client = aggregation.fused_sys_block(dev)
-            w = w0 * arrivals["received"][client]
-            if state.c == 0:
-                return aggregation.round_gradient(
-                    x, y, beta, w=w, path=aggregation.FUSED)
-            return aggregation.fused_coded_gradient(
-                dev, w, arrivals["parity_ok"], beta)
-        resid = dev["x"] @ beta - dev["y"]
-        w = dev["w_sys"] * arrivals["received"][dev["row_client"]]
-        g_sys = (resid * w) @ dev["x"]
+        x, y, w0, client = aggregation.fused_sys_block(dev)
+        w = w0 * arrivals["received"][client]
         if state.c == 0:  # delta = 0 degenerates to uncoded FL w/ deadline
-            return g_sys
-        g_par = aggregation.parity_gradient(
-            dev["x_parity"], dev["y_parity"], beta,
-            use_kernel=self.use_kernel)
-        return g_sys + arrivals["parity_ok"] * g_par
+            return aggregation.round_gradient(
+                x, y, beta, w=w, path=aggregation.FUSED)
+        return aggregation.fused_coded_gradient(
+            dev, w, arrivals["parity_ok"], beta)
 
     def tiered_contributions(self, state, dev, beta, arrivals, tier_masks):
         # systematic feature-space partials reduce per edge tier; the
         # parity gradient is server-resident and rides as the server term
-        if self._grad_path() == aggregation.FUSED:
-            x, y, w0, client = aggregation.fused_sys_block(dev)
-            masks = aggregation.fused_tier_masks(dev, tier_masks)
-            w = w0 * arrivals["received"][client]
-            partials = aggregation.tiered_round_gradient(
-                x, y, beta, w, masks, path=aggregation.FUSED)
-            if state.c == 0:
-                return partials, None
-            g_par = aggregation.gram_parity_gradient(
-                dev["par_gram"], dev["par_gramy"], beta, dev["par_c"])
-            return partials, arrivals["parity_ok"] * g_par
-        resid = dev["x"] @ beta - dev["y"]
-        w = dev["w_sys"] * arrivals["received"][dev["row_client"]]
-        partials = aggregation.tier_reduce(resid * w, dev["x"], tier_masks)
+        x, y, w0, client = aggregation.fused_sys_block(dev)
+        masks = aggregation.fused_tier_masks(dev, tier_masks)
+        w = w0 * arrivals["received"][client]
+        partials = aggregation.tiered_round_gradient(
+            x, y, beta, w, masks, path=aggregation.FUSED)
         if state.c == 0:
             return partials, None
-        g_par = aggregation.parity_gradient(
-            dev["x_parity"], dev["y_parity"], beta,
-            use_kernel=self.use_kernel)
+        g_par = aggregation.gram_parity_gradient(
+            dev["par_gram"], dev["par_gramy"], beta, dev["par_c"])
         return partials, arrivals["parity_ok"] * g_par
 
     def uplink_bits(self, state: CodedFedLState, fleet: "FleetSpec",
@@ -260,8 +222,7 @@ class CodedFedL:
         return cfl.coded_uplink_bits(state, fleet, epochs)
 
     def engine_key(self, state: CodedFedLState) -> Hashable:
-        return (state.c > 0, self.use_kernel, self.d_feat,
-                self._grad_path())
+        return (state.c > 0, self.d_feat)
 
     def sweep_inputs(self, state: CodedFedLState, fleet: "FleetSpec",
                      epochs: int, rng: np.random.Generator) -> EpochSchedule:

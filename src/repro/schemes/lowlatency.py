@@ -40,7 +40,7 @@ from repro.core import aggregation, encoding
 from repro.core.delay_model import partial_cdf, sample_total
 from repro.core.redundancy import RedundancyPlan
 
-from .base import (CodedSchemeState, coded_device_state, coded_uplink_bits,
+from .base import (CodedSchemeState, coded_uplink_bits,
                    fused_coded_device_state, sample_parity_upload_time)
 
 if TYPE_CHECKING:  # annotation-only: keeps schemes free of sim imports
@@ -84,18 +84,14 @@ class LowLatencyCFL:
     generator: str = "normal"
     label: str = "lowlat"
     redundancy_plan: Optional[RedundancyPlan] = None
-    grad_path: str = aggregation.FUSED
-
-    def _grad_path(self) -> str:
-        return aggregation.resolve_grad_path(self.grad_path)
 
     # all knobs (chunks included) reach the traced engine only through
-    # operand values — row_chunk ids, chunks_done counts, the plan — so
+    # operand values — sys_chunk ids, chunks_done counts, the plan — so
     # a whole chunking/heterogeneity sweep shares one compiled engine
     engine_value_fields: ClassVar[frozenset] = frozenset(
         {"chunks", "fixed_c", "c_up", "include_upload_delay", "generator"})
-    # data-only operands (one replicated copy per sweep); row_chunk is
-    # plan-derived and stays per-lane
+    # data-only operands of the dense layout (one replicated copy per
+    # sweep); sys_chunk is plan-derived and stays per-lane
     data_device_keys: ClassVar[frozenset] = frozenset(
         {"x", "y", "row_client"})
 
@@ -200,20 +196,16 @@ class LowLatencyCFL:
 
     def device_state(self, state: LowLatencyState,
                      data: TrainData) -> Dict[str, jax.Array]:
-        if self._grad_path() == aggregation.FUSED:
-            # copy: the packed dict is memoized on the state and must not
-            # absorb per-strategy extras
-            dev = dict(fused_coded_device_state(state, data))
-            rc = state.row_chunk.reshape(data.m)
-            if "sys_rows" in dev:
-                rc = rc[np.asarray(dev["sys_rows"])]
-            dev["sys_chunk"] = jnp.asarray(rc)
-            return dev
-        dev = coded_device_state(state, data)
-        dev["row_chunk"] = jnp.asarray(state.row_chunk.reshape(data.m))
+        # copy: the packed dict is memoized on the state and must not
+        # absorb per-strategy extras
+        dev = dict(fused_coded_device_state(state, data))
+        rc = state.row_chunk.reshape(data.m)
+        if "sys_rows" in dev:
+            rc = rc[np.asarray(dev["sys_rows"])]
+        dev["sys_chunk"] = jnp.asarray(rc)
         return dev
 
-    def _fused_weights(self, dev, arrivals):
+    def _weights(self, dev, arrivals):
         # a row contributes iff its chunk completed by t*
         x, _, w0, client = aggregation.fused_sys_block(dev)
         done = arrivals["chunks_done"][client]
@@ -221,47 +213,26 @@ class LowLatencyCFL:
         return w0 * gate
 
     def round_contributions(self, state, dev, beta, arrivals):
-        if self._grad_path() == aggregation.FUSED:
-            x, y, _, _ = aggregation.fused_sys_block(dev)
-            w = self._fused_weights(dev, arrivals)
-            if state.c == 0:
-                return aggregation.round_gradient(
-                    x, y, beta, w=w, path=aggregation.FUSED)
-            return aggregation.fused_coded_gradient(
-                dev, w, arrivals["parity_ok"], beta)
-        resid = dev["x"] @ beta - dev["y"]
-        # a row contributes iff its chunk completed by t*
-        done = arrivals["chunks_done"][dev["row_client"]]
-        w = dev["w_sys"] * (dev["row_chunk"] < done).astype(resid.dtype)
-        g_sys = (resid * w) @ dev["x"]
+        x, y, _, _ = aggregation.fused_sys_block(dev)
+        w = self._weights(dev, arrivals)
         if state.c == 0:
-            return g_sys
-        g_par = aggregation.parity_gradient(
-            dev["x_parity"], dev["y_parity"], beta)
-        return g_sys + arrivals["parity_ok"] * g_par
+            return aggregation.round_gradient(
+                x, y, beta, w=w, path=aggregation.FUSED)
+        return aggregation.fused_coded_gradient(
+            dev, w, arrivals["parity_ok"], beta)
 
     def tiered_contributions(self, state, dev, beta, arrivals, tier_masks):
         # chunk-gated systematic partials reduce per edge tier; parity is
         # server-resident and rides as the server-side term
-        if self._grad_path() == aggregation.FUSED:
-            x, y, _, _ = aggregation.fused_sys_block(dev)
-            masks = aggregation.fused_tier_masks(dev, tier_masks)
-            w = self._fused_weights(dev, arrivals)
-            partials = aggregation.tiered_round_gradient(
-                x, y, beta, w, masks, path=aggregation.FUSED)
-            if state.c == 0:
-                return partials, None
-            g_par = aggregation.gram_parity_gradient(
-                dev["par_gram"], dev["par_gramy"], beta, dev["par_c"])
-            return partials, arrivals["parity_ok"] * g_par
-        resid = dev["x"] @ beta - dev["y"]
-        done = arrivals["chunks_done"][dev["row_client"]]
-        w = dev["w_sys"] * (dev["row_chunk"] < done).astype(resid.dtype)
-        partials = aggregation.tier_reduce(resid * w, dev["x"], tier_masks)
+        x, y, _, _ = aggregation.fused_sys_block(dev)
+        masks = aggregation.fused_tier_masks(dev, tier_masks)
+        w = self._weights(dev, arrivals)
+        partials = aggregation.tiered_round_gradient(
+            x, y, beta, w, masks, path=aggregation.FUSED)
         if state.c == 0:
             return partials, None
-        g_par = aggregation.parity_gradient(
-            dev["x_parity"], dev["y_parity"], beta)
+        g_par = aggregation.gram_parity_gradient(
+            dev["par_gram"], dev["par_gramy"], beta, dev["par_c"])
         return partials, arrivals["parity_ok"] * g_par
 
     def uplink_bits(self, state: LowLatencyState, fleet: "FleetSpec",

@@ -63,7 +63,7 @@ from repro.core import aggregation, encoding
 from repro.core.delay_model import sample_total
 from repro.core.redundancy import RedundancyPlan, systematic_weights
 
-from .base import (CodedSchemeState, coded_device_state, coded_uplink_bits,
+from .base import (CodedSchemeState, coded_uplink_bits,
                    fused_coded_device_state, sample_parity_upload_time)
 
 if TYPE_CHECKING:  # annotation-only: keeps schemes free of sim imports
@@ -120,10 +120,6 @@ class StochasticCodedFL:
     epsilon_target: Optional[float] = None
     delta: float = 1e-5
     rounds: Optional[int] = None
-    grad_path: str = aggregation.FUSED
-
-    def _grad_path(self) -> str:
-        return aggregation.resolve_grad_path(self.grad_path)
 
     # noise / budget knobs feed the plan, the encoded values and the DP
     # accounting report — never the traced engine — so a whole
@@ -132,8 +128,8 @@ class StochasticCodedFL:
     engine_value_fields: ClassVar[frozenset] = frozenset(
         {"fixed_c", "c_up", "include_upload_delay", "generator",
          "noise_multiplier", "epsilon_target", "delta", "rounds"})
-    # data-only operands (one replicated copy per sweep); the noised
-    # parity shards and load mask stay per-lane
+    # data-only operands of the dense layout (one replicated copy per
+    # sweep); the noised parity factors and row weights stay per-lane
     data_device_keys: ClassVar[frozenset] = frozenset(
         {"x", "y", "row_client"})
 
@@ -278,14 +274,12 @@ class StochasticCodedFL:
 
     def device_state(self, state: StochasticState,
                      data: TrainData) -> Dict[str, jax.Array]:
-        if self._grad_path() == aggregation.FUSED:
-            # rho < 1 keeps the raw parity rows alongside the Gram
-            # factors: the per-round Bernoulli mask needs the rows
-            return fused_coded_device_state(
-                state, data, parity_rows=self.sample_frac < 1.0)
-        return coded_device_state(state, data)
+        # rho < 1 keeps the raw parity rows alongside the Gram factors:
+        # the per-round Bernoulli mask needs the rows
+        return fused_coded_device_state(
+            state, data, parity_rows=self.sample_frac < 1.0)
 
-    def _fused_round(self, state, dev, beta, arrivals):
+    def round_contributions(self, state, dev, beta, arrivals):
         x, y, w0, client = aggregation.fused_sys_block(dev)
         w = w0 * arrivals["received"][client]
         if state.c == 0:
@@ -293,8 +287,9 @@ class StochasticCodedFL:
                 x, y, beta, w=w, path=aggregation.FUSED)
         if self.sample_frac < 1.0:
             # inverse-probability row weights keep the subsampled parity
-            # gradient unbiased; folding 1/(c*rho) into them lets the
-            # systematic and parity streams share ONE fused launch
+            # gradient unbiased (E[mask/rho] = 1 per row); folding
+            # 1/(c*rho) into them lets the systematic and parity streams
+            # share ONE fused launch
             w_par = arrivals["parity_mask"] \
                 * (arrivals["parity_ok"]
                    / (dev["par_c"] * self.sample_frac))
@@ -305,54 +300,27 @@ class StochasticCodedFL:
         return aggregation.fused_coded_gradient(
             dev, w, arrivals["parity_ok"], beta, rho=self.sample_frac)
 
-    def round_contributions(self, state, dev, beta, arrivals):
-        if self._grad_path() == aggregation.FUSED:
-            return self._fused_round(state, dev, beta, arrivals)
-        resid = dev["x"] @ beta - dev["y"]
-        w = dev["w_sys"] * arrivals["received"][dev["row_client"]]
-        g_sys = (resid * w) @ dev["x"]
-        if state.c == 0:
-            return g_sys
-        resid_par = dev["x_parity"] @ beta - dev["y_parity"]
-        w_par = arrivals["parity_mask"] * arrivals["parity_ok"]
-        # inverse-probability weighting keeps the subsampled parity
-        # gradient unbiased: E[mask/rho] = 1 per row
-        g_par = ((resid_par * w_par) @ dev["x_parity"]) \
-            / (state.c * self.sample_frac)
-        return g_sys + g_par
-
     def tiered_contributions(self, state, dev, beta, arrivals, tier_masks):
         # systematic partials reduce per edge tier; the stochastic parity
         # gradient is server-resident and rides as the server-side term
-        if self._grad_path() == aggregation.FUSED:
-            x, y, w0, client = aggregation.fused_sys_block(dev)
-            masks = aggregation.fused_tier_masks(dev, tier_masks)
-            w = w0 * arrivals["received"][client]
-            partials = aggregation.tiered_round_gradient(
-                x, y, beta, w, masks, path=aggregation.FUSED)
-            if state.c == 0:
-                return partials, None
-            c_norm = dev["par_c"] * self.sample_frac
-            if self.sample_frac < 1.0:
-                w_par = arrivals["parity_mask"] \
-                    * (arrivals["parity_ok"] / c_norm)
-                g_par = aggregation.round_gradient(
-                    dev["x_parity"], dev["y_parity"], beta, w=w_par,
-                    path=aggregation.FUSED)
-            else:
-                g_par = arrivals["parity_ok"] \
-                    * aggregation.gram_parity_gradient(
-                        dev["par_gram"], dev["par_gramy"], beta, c_norm)
-            return partials, g_par
-        resid = dev["x"] @ beta - dev["y"]
-        w = dev["w_sys"] * arrivals["received"][dev["row_client"]]
-        partials = aggregation.tier_reduce(resid * w, dev["x"], tier_masks)
+        x, y, w0, client = aggregation.fused_sys_block(dev)
+        masks = aggregation.fused_tier_masks(dev, tier_masks)
+        w = w0 * arrivals["received"][client]
+        partials = aggregation.tiered_round_gradient(
+            x, y, beta, w, masks, path=aggregation.FUSED)
         if state.c == 0:
             return partials, None
-        resid_par = dev["x_parity"] @ beta - dev["y_parity"]
-        w_par = arrivals["parity_mask"] * arrivals["parity_ok"]
-        g_par = ((resid_par * w_par) @ dev["x_parity"]) \
-            / (state.c * self.sample_frac)
+        c_norm = dev["par_c"] * self.sample_frac
+        if self.sample_frac < 1.0:
+            w_par = arrivals["parity_mask"] \
+                * (arrivals["parity_ok"] / c_norm)
+            g_par = aggregation.round_gradient(
+                dev["x_parity"], dev["y_parity"], beta, w=w_par,
+                path=aggregation.FUSED)
+        else:
+            g_par = arrivals["parity_ok"] \
+                * aggregation.gram_parity_gradient(
+                    dev["par_gram"], dev["par_gramy"], beta, c_norm)
         return partials, g_par
 
     def uplink_bits(self, state: StochasticState, fleet: "FleetSpec",

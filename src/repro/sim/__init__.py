@@ -1,8 +1,4 @@
 """Wall-clock simulation of heterogeneous federated fleets (paper §IV)."""
 from .network import FleetSpec, make_fleet, paper_fleet
-from .simulator import (SimResult, TraceReport, coding_gain,
-                        convergence_time, run_cfl, run_uncoded)
 
-__all__ = ["FleetSpec", "make_fleet", "paper_fleet", "SimResult",
-           "TraceReport", "run_uncoded", "run_cfl", "convergence_time",
-           "coding_gain"]
+__all__ = ["FleetSpec", "make_fleet", "paper_fleet"]

@@ -6,6 +6,7 @@ draw order, same arrival masks, same fp32 gradient arithmetic — so the
 legacy reference loops are reimplemented here (from the seed code) and the
 new engine is checked against them trace-for-trace.
 """
+import _oracle as oracle
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,8 +14,7 @@ import pytest
 from _hyp import given, settings, st  # hypothesis, or a deterministic fallback
 
 from repro.api import (CodedFL, EpochSchedule, GradientCodingFL, Session,
-                       TraceReport, TrainData, UncodedFL, coding_gain,
-                       convergence_time)
+                       TrainData, UncodedFL, coding_gain, convergence_time)
 from repro.core import aggregation, cfl
 from repro.core.delay_model import sample_total
 from repro.sim.network import paper_fleet, wireless_fleet
@@ -35,6 +35,7 @@ def _legacy_run_uncoded(fleet, data, lr, epochs, rng):
     xs, ys, beta_true = data.xs, data.ys, data.beta_true
     n, ell, d = xs.shape
     m = n * ell
+    x, y = xs.reshape(m, d), ys.reshape(m)
     beta = jnp.zeros(d, dtype=xs.dtype)
     full_load = np.full(n, ell)
     errs = [float(aggregation.nmse(beta, beta_true))]
@@ -42,7 +43,7 @@ def _legacy_run_uncoded(fleet, data, lr, epochs, rng):
     for _ in range(epochs):
         t_i = sample_total(fleet.edge, full_load, rng)
         durs.append(float(np.max(t_i)))
-        g = aggregation.uncoded_full_gradient(xs, ys, beta)
+        g = (x @ beta - y) @ x
         beta = aggregation.gd_update(beta, g, lr, m)
         errs.append(float(aggregation.nmse(beta, beta_true)))
     return np.array(errs), np.array(durs)
@@ -75,10 +76,45 @@ def _legacy_run_cfl(fleet, data, lr, epochs, rng, key, fixed_c,
         else:
             t_srv = sample_total(fleet.server, np.array([state.c]), rng)[0]
             par_ok = jnp.asarray(float(t_srv <= t_star), dtype=xs.dtype)
-        g = cfl.epoch_gradient(state, xs, ys, beta, received, par_ok)
+        # systematic partials over each client's load, summed over the
+        # clients whose partial arrived by t*, plus the Eq.-18 parity term
+        resid = (jnp.einsum("nld,d->nl", xs, beta) - ys) * state.load_mask
+        partials = jnp.einsum("nld,nl->nd", xs, resid)
+        g = jnp.einsum("nd,n->d", partials, received)
+        if state.c > 0:
+            g_par = (state.x_parity @ beta - state.y_parity) \
+                @ state.x_parity / state.c
+            g = g + par_ok * g_par
         beta = aggregation.gd_update(beta, g, lr, m)
         errs.append(float(aggregation.nmse(beta, beta_true)))
     return np.array(errs), upload_time, t_star
+
+
+def _legacy_run_gradcoding(fleet, data, lr, epochs, rng, r):
+    """Fractional-repetition gradient coding as a per-epoch loop: each
+    client computes its group's r shards, an epoch ends when every group
+    has a returner, and the decoded gradient is the full one."""
+    xs, ys, beta_true = data.xs, data.ys, data.beta_true
+    n, ell, d = xs.shape
+    m = n * ell
+    x, y = xs.reshape(m, d), ys.reshape(m)
+    groups = np.repeat(np.arange(n // r), r)
+    share_bits = (r - 1) * ell * (d + 1) * 32 * 1.1
+    setup = float(np.max(share_bits / fleet.link_rates))
+    loads = np.full(n, r * ell)
+    beta = jnp.zeros(d, dtype=xs.dtype)
+    errs = [float(aggregation.nmse(beta, beta_true))]
+    durs = []
+    for _ in range(epochs):
+        t_i = sample_total(fleet.edge, loads, rng)
+        per_group = np.full(n // r, np.inf)
+        for i, g in enumerate(groups):
+            per_group[g] = min(per_group[g], t_i[i])
+        durs.append(float(per_group.max()))
+        g = (x @ beta - y) @ x
+        beta = aggregation.gd_update(beta, g, lr, m)
+        errs.append(float(aggregation.nmse(beta, beta_true)))
+    return np.array(errs), np.array(durs), setup
 
 
 # ---------------------------------------------------------------------------
@@ -97,63 +133,32 @@ def test_uncoded_session_matches_legacy_trace(small):
 
 
 def test_cfl_session_matches_legacy_trace(small):
-    """grad_path="reference" pinned: this is the bit-stability contract
-    against the pre-fusion per-epoch loop (tight rtol); the fused
-    default is checked separately below at its documented tolerance."""
+    """The fused Session against the per-epoch loop: the packed rows and
+    the Gram-folded parity reassociate float sums, hence rtol 1e-3."""
     fleet, data = small
     c = int(0.3 * data.m)
     errs, upload, t_star = _legacy_run_cfl(
         fleet, data, lr=0.05, epochs=100, rng=np.random.default_rng(0),
         key=jax.random.PRNGKey(1), fixed_c=c)
-    session = Session(
-        strategy=CodedFL(key=jax.random.PRNGKey(1), fixed_c=c,
-                         grad_path="reference"),
-        fleet=fleet, lr=0.05, epochs=100)
-    rep = session.run(data, rng=np.random.default_rng(0))
-    np.testing.assert_allclose(rep.nmse, errs, rtol=1e-4, atol=1e-7)
+    rep = Session(
+        strategy=CodedFL(key=jax.random.PRNGKey(1), fixed_c=c),
+        fleet=fleet, lr=0.05, epochs=100).run(
+            data, rng=np.random.default_rng(0))
+    np.testing.assert_allclose(rep.nmse, errs, rtol=1e-3, atol=1e-6)
     assert rep.setup_time == pytest.approx(upload)
     assert rep.times[0] == pytest.approx(upload)  # upload delay included
     np.testing.assert_allclose(rep.epoch_durations, t_star)
 
-    # fused default: same legacy trace at the fused path's tolerance
-    fused = Session(
-        strategy=CodedFL(key=jax.random.PRNGKey(1), fixed_c=c),
-        fleet=fleet, lr=0.05, epochs=100).run(
-            data, rng=np.random.default_rng(0))
-    np.testing.assert_allclose(fused.nmse, errs, rtol=1e-3, atol=1e-6)
-    np.testing.assert_array_equal(fused.epoch_durations,
-                                  rep.epoch_durations)
-
-
-def test_cfl_shim_equals_direct_session(small):
-    """The deprecated run_cfl entry point is the same computation."""
-    from repro.sim.simulator import run_cfl
-    fleet, data = small
-    c = int(0.2 * data.m)
-    shim = run_cfl(fleet, data.xs, data.ys, data.beta_true, lr=0.05,
-                   epochs=40, rng=np.random.default_rng(3),
-                   key=jax.random.PRNGKey(2), fixed_c=c,
-                   include_upload_delay=False)
-    direct = Session(
-        strategy=CodedFL(key=jax.random.PRNGKey(2), fixed_c=c,
-                         include_upload_delay=False),
-        fleet=fleet, lr=0.05, epochs=40).run(
-            data, rng=np.random.default_rng(3))
-    np.testing.assert_array_equal(shim.nmse, direct.nmse)
-    np.testing.assert_array_equal(shim.times, direct.times)
-    assert shim.uplink_bits_total == direct.uplink_bits_total
-    assert isinstance(shim, TraceReport)
-
 
 def test_gradcoding_session_matches_legacy_trace(small):
-    from repro.core.gradient_coding import run_gradient_coding
     fleet, data = small
     rep = Session(strategy=GradientCodingFL(r=3), fleet=fleet, lr=0.05,
                   epochs=60).run(data, rng=np.random.default_rng(0))
-    shim = run_gradient_coding(fleet, data.xs, data.ys, data.beta_true,
-                               lr=0.05, epochs=60,
-                               rng=np.random.default_rng(0), r=3)
-    np.testing.assert_array_equal(rep.nmse, shim.nmse)
+    errs, durs, setup = _legacy_run_gradcoding(
+        fleet, data, lr=0.05, epochs=60, rng=np.random.default_rng(0), r=3)
+    np.testing.assert_allclose(rep.nmse, errs, rtol=1e-5, atol=1e-7)
+    np.testing.assert_array_equal(rep.epoch_durations, durs)
+    assert rep.setup_time == pytest.approx(setup)
     assert rep.setup_time > 0
     assert rep.times[0] == pytest.approx(rep.setup_time)
     # waiting for every group => gradient is exact => same NMSE trajectory
@@ -269,9 +274,8 @@ def test_gradcoding_recovers_exact_full_gradient(n_groups, r, d):
     g = strat.round_contributions(
         state, dev, beta,
         {"group_ok": jnp.ones(state.n_groups, dtype=jnp.float32)})
-    full = aggregation.uncoded_full_gradient(data.xs, data.ys, beta)
-    np.testing.assert_allclose(np.asarray(g), np.asarray(full),
-                               rtol=1e-4, atol=1e-4)
+    full = oracle.full_gradient(data.xs, data.ys, beta)
+    np.testing.assert_allclose(np.asarray(g), full, rtol=1e-4, atol=1e-4)
 
 
 def test_gradcoding_partial_groups_drop_cleanly():
@@ -285,9 +289,8 @@ def test_gradcoding_partial_groups_drop_cleanly():
     ok = jnp.asarray([1.0, 0.0, 1.0], dtype=jnp.float32)
     g = strat.round_contributions(state, dev, beta, {"group_ok": ok})
     mask = np.repeat(np.asarray(ok), 2)  # fractional repetition: r=2
-    per_client = aggregation.client_partial_gradients(
-        data.xs, data.ys, jnp.ones(data.xs.shape[:2]), beta)
-    expect = np.einsum("nd,n->d", np.asarray(per_client), mask)
+    per_client = oracle.client_gradients(data.xs, data.ys, beta)
+    expect = np.einsum("nd,n->d", per_client, mask)
     np.testing.assert_allclose(np.asarray(g), expect, rtol=1e-4, atol=1e-4)
 
 
@@ -354,8 +357,8 @@ def test_custom_strategy_plugs_in(small):
 
         def round_contributions(self, state, dev, beta, arrivals):
             xs, ys = dev["xs"], dev["ys"]
-            partials = aggregation.client_partial_gradients(
-                xs, ys, jnp.ones(xs.shape[:2], xs.dtype), beta)
+            resid = jnp.einsum("nld,d->nl", xs, beta) - ys
+            partials = jnp.einsum("nld,nl->nd", xs, resid)
             return jnp.einsum("nd,n->d", partials, arrivals["received"])
 
         def uplink_bits(self, state, fleet, epochs):

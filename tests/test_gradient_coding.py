@@ -1,12 +1,35 @@
 """Tests for the gradient-coding baseline (paper ref [5] comparator)."""
+import _oracle as oracle
 import jax
 import numpy as np
 import pytest
 
+from repro.api import GradientCodingFL, Session, TrainData
 from repro.core import gradient_coding as GC
-from repro.core import aggregation
-from repro.sim import simulator as S
+from repro.core.delay_model import sample_total
 from repro.sim.network import paper_fleet
+
+
+def group_gradients(xs, ys, beta, plan):
+    """Each group's exact gradient over all its members' data:
+    (n_groups, d)."""
+    per_client = oracle.client_gradients(xs, ys, beta)         # (n, d)
+    n_groups = int(plan.groups.max()) + 1
+    onehot = np.arange(n_groups)[None, :] == plan.groups[:, None]
+    return onehot.T.astype(np.float64) @ per_client
+
+
+def epoch_time(fleet, plan, ell, rng):
+    """Wall time until every group has >= 1 returner: each client
+    processes r*ell points (its whole group's data), and the epoch ends
+    at max over groups of (min over group members)."""
+    loads = np.full(fleet.edge.n, plan.r * ell)
+    t_i = sample_total(fleet.edge, loads, rng)
+    n_groups = int(plan.groups.max()) + 1
+    per_group = np.full(n_groups, np.inf)
+    for i, g in enumerate(plan.groups):
+        per_group[g] = min(per_group[g], t_i[i])
+    return float(per_group.max())
 
 
 def test_make_plan_groups():
@@ -24,14 +47,12 @@ def test_make_plan_rejects_non_divisor():
 
 
 def test_group_gradients_partition_full_gradient():
-    key = jax.random.PRNGKey(0)
-    xs, ys, bt = S.generate_linreg(key, n=8, ell=10, d=6)
+    data = TrainData.linreg(jax.random.PRNGKey(0), n=8, ell=10, d=6)
     plan = GC.make_plan(8, 2)
     beta = jax.random.normal(jax.random.PRNGKey(1), (6,))
-    gg = GC.group_gradients(xs, ys, beta, plan)
-    full = aggregation.uncoded_full_gradient(xs, ys, beta)
-    np.testing.assert_allclose(np.asarray(gg.sum(axis=0)), np.asarray(full),
-                               rtol=1e-4, atol=1e-4)
+    gg = group_gradients(data.xs, data.ys, beta, plan)
+    full = oracle.full_gradient(data.xs, data.ys, beta)
+    np.testing.assert_allclose(gg.sum(axis=0), full, rtol=1e-4, atol=1e-4)
 
 
 def test_epoch_time_decreases_with_replication():
@@ -41,9 +62,9 @@ def test_epoch_time_decreases_with_replication():
     equals the uncoded max, and all times are positive/finite."""
     fleet = paper_fleet(0.2, 0.2, seed=0, n=12, d=50)
     rng = np.random.default_rng(0)
-    t1 = [GC.epoch_time(fleet, GC.make_plan(12, 1), 50, rng)
+    t1 = [epoch_time(fleet, GC.make_plan(12, 1), 50, rng)
           for _ in range(50)]
-    t3 = [GC.epoch_time(fleet, GC.make_plan(12, 3), 50, rng)
+    t3 = [epoch_time(fleet, GC.make_plan(12, 3), 50, rng)
           for _ in range(50)]
     assert all(np.isfinite(t1)) and all(np.isfinite(t3))
     assert min(t1 + t3) > 0
@@ -54,13 +75,8 @@ def test_vectorized_sample_epochs_matches_legacy_loop():
     `GradientCodingFL.sample_epochs` reproduces the seed's per-client
     Python loop trace-identically (same generator draws, same epoch
     durations, bit for bit)."""
-    from repro.api import GradientCodingFL, TrainData
-    from repro.core.delay_model import sample_total
-
     fleet = paper_fleet(0.2, 0.2, seed=0, n=12, d=50)
-    data = TrainData(*[jax.numpy.asarray(v) for v in
-                       S.generate_linreg(jax.random.PRNGKey(0),
-                                         n=12, ell=30, d=50)])
+    data = TrainData.linreg(jax.random.PRNGKey(0), n=12, ell=30, d=50)
     strat = GradientCodingFL(r=3)
     state = strat.plan(fleet, data)
     epochs = 40
@@ -90,17 +106,13 @@ def test_sample_epochs_matches_frozen_loop(fleet_kind, r):
     """`GradientCodingFL.sample_epochs` (and `sweep_inputs`) against a
     frozen copy of its per-epoch sampling loop: the same schedule bit for
     bit, and the generator left where the loop leaves it."""
-    from repro.api import GradientCodingFL, TrainData
-    from repro.core.delay_model import sample_total
     from repro.sim.network import wireless_fleet
 
     if fleet_kind == "paper":
         fleet = paper_fleet(0.2, 0.2, seed=4, n=12, d=50)
     else:
         fleet = wireless_fleet(0.2, 0.2, nu_erasure=0.3, seed=4, n=12, d=50)
-    data = TrainData(*[jax.numpy.asarray(v) for v in
-                       S.generate_linreg(jax.random.PRNGKey(0),
-                                         n=12, ell=30, d=50)])
+    data = TrainData.linreg(jax.random.PRNGKey(0), n=12, ell=30, d=50)
     strat = GradientCodingFL(r=r)
     state = strat.plan(fleet, data)
     epochs = 120
@@ -131,9 +143,8 @@ def test_sample_epochs_matches_frozen_loop(fleet_kind, r):
 
 def test_gradient_coding_converges():
     fleet = paper_fleet(0.2, 0.2, seed=1, n=12, d=60)
-    key = jax.random.PRNGKey(0)
-    xs, ys, bt = S.generate_linreg(key, n=12, ell=80, d=60)
-    res = GC.run_gradient_coding(fleet, xs, ys, bt, lr=0.05, epochs=200,
-                                 rng=np.random.default_rng(0), r=3)
+    data = TrainData.linreg(jax.random.PRNGKey(0), n=12, ell=80, d=60)
+    res = Session(strategy=GradientCodingFL(r=3), fleet=fleet, lr=0.05,
+                  epochs=200).run(data, rng=np.random.default_rng(0))
     assert res.final_nmse() < 1e-2
     assert res.setup_time > 0  # raw-data sharing cost is accounted

@@ -1,6 +1,5 @@
-"""Tests for the fused round-gradient path (`repro.kernels.round_grad`
-and the `grad_path` plumbing through `repro.core.aggregation` and every
-strategy).
+"""Tests for the fused round-gradient path (`repro.kernels.round_grad`,
+the dispatchers in `repro.core.aggregation` and every strategy's round).
 
 Four layers of guarantees:
 
@@ -10,15 +9,14 @@ Four layers of guarantees:
     `w=None` and `c == 0` degenerate cases, and a scalar parity weight;
   * packing — `packed_row_indices` bucket-pads the systematic support
     and the padding rows carry weight zero (exact-zero contributions);
-  * session parity — every strategy's `grad_path="fused"` trace matches
-    its `grad_path="reference"` trace to rtol 1e-3 / atol 1e-6 with
-    bit-identical durations, flat and tiered, and the deprecated
-    `CodedFL.use_kernel=True` shim is bitwise the fused default;
-  * reference stability — `grad_path="reference"` lowers to exactly the
-    pre-fusion expressions (`array_equal` against hand-written jnp).
+  * session parity — every strategy's `Session` trace matches the
+    float64 NumPy oracle (`_oracle.run`, over the same plan and the same
+    drawn schedule) to rtol 1e-3 / atol 1e-6 with bit-identical
+    durations, flat and tiered, under both operand layouts;
+  * dispatcher stability — the dispatchers' jnp expressions are exactly
+    the two-pass sums (`array_equal` against hand-written jnp).
 """
-import dataclasses
-
+import _oracle as oracle
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -260,7 +258,7 @@ def test_fused_device_state_dense_fallback(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# session parity: fused vs reference, all strategies
+# session parity: Session vs the float64 oracle, all strategies
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -305,16 +303,22 @@ def _case(name: str, data):
     raise ValueError(name)
 
 
-def _run(strategy, flt, data, seed=3):
-    return Session(strategy=strategy, fleet=flt, lr=LR, epochs=EPOCHS,
-                   seed=seed).run(data, rng=np.random.default_rng(seed))
-
-
-def _assert_trace_parity(fused, ref):
-    np.testing.assert_array_equal(fused.epoch_durations,
-                                  ref.epoch_durations)
-    np.testing.assert_array_equal(fused.times, ref.times)
-    np.testing.assert_allclose(fused.nmse, ref.nmse, rtol=1e-3, atol=1e-6)
+def _assert_matches_oracle(strategy, flt, data, seed=3):
+    """One planned state, one schedule drawn from `seed`: the Session's
+    trace against the oracle's replay of the same epochs."""
+    state = strategy.plan(flt, data)
+    rep = Session(strategy=strategy, fleet=flt, lr=LR, epochs=EPOCHS,
+                  seed=seed).run(data, rng=np.random.default_rng(seed),
+                                 state=state)
+    sched = strategy.sample_epochs(state, flt, EPOCHS,
+                                   np.random.default_rng(seed))
+    nmse, times, beta = oracle.run(strategy, state, data, sched, LR)
+    np.testing.assert_array_equal(rep.epoch_durations, sched.durations)
+    np.testing.assert_array_equal(rep.times, times)
+    np.testing.assert_allclose(rep.nmse, nmse, rtol=1e-3, atol=1e-6)
+    # the NMSE of a slow learner (cfedl_rff) barely moves in EPOCHS
+    # epochs; the iterate itself pins every gradient
+    np.testing.assert_allclose(rep.beta, beta, rtol=1e-3, atol=1e-6)
 
 
 # The small-fleet plans load nearly every row, so the natural layout is
@@ -330,58 +334,25 @@ def test_fused_matches_reference_flat(name, layout, small, monkeypatch):
     fleet, wfleet, data = small
     strat, which = _case(name, data)
     flt = fleet if which == "paper" else wfleet
-    assert strat.grad_path == aggregation.FUSED  # fused is the default
-    fused = _run(strat, flt, data)
-    ref = _run(dataclasses.replace(strat,
-                                   grad_path=aggregation.REFERENCE),
-               flt, data)
-    _assert_trace_parity(fused, ref)
+    _assert_matches_oracle(strat, flt, data)
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
-@pytest.mark.parametrize("name", ["cfl", "scfl_rho", "lowlat"])
+@pytest.mark.parametrize("name", ["cfl", "scfl", "scfl_rho", "lowlat",
+                                  "cfedl_rff", "cfedl_id"])
 def test_fused_matches_reference_tiered(name, layout, small, monkeypatch):
     monkeypatch.setattr(cfl, "PACK_DENSE_FRAC", LAYOUTS[layout])
     fleet, wfleet, data = small
     strat, which = _case(name, data)
     flt = fleet if which == "paper" else wfleet
     topo = FleetTopology.uniform(N, 3)
-    fused = _run(make_strategy("hierarchical", base=strat, topology=topo),
-                 flt, data)
-    ref = _run(make_strategy(
-        "hierarchical", topology=topo,
-        base=dataclasses.replace(strat,
-                                 grad_path=aggregation.REFERENCE)),
+    _assert_matches_oracle(
+        make_strategy("hierarchical", base=strat, topology=topo),
         flt, data)
-    _assert_trace_parity(fused, ref)
-
-
-def test_use_kernel_shim_is_fused_bitwise(small):
-    """Deprecated `use_kernel=True` must route to the fused path and
-    reproduce the fused default exactly (same engine, same trace)."""
-    fleet, _, data = small
-    c = int(0.3 * data.m)
-    default = _run(make_strategy("cfl", key_seed=7, fixed_c=c),
-                   fleet, data)
-    shim = _run(make_strategy("cfl", key_seed=7, fixed_c=c,
-                              use_kernel=True), fleet, data)
-    np.testing.assert_array_equal(shim.nmse, default.nmse)
-    np.testing.assert_array_equal(shim.epoch_durations,
-                                  default.epoch_durations)
-
-
-def test_resolve_grad_path_validates():
-    assert aggregation.resolve_grad_path("fused") == aggregation.FUSED
-    assert aggregation.resolve_grad_path("reference") == \
-        aggregation.REFERENCE
-    assert aggregation.resolve_grad_path(
-        "reference", use_kernel=True) == aggregation.FUSED
-    with pytest.raises(ValueError):
-        aggregation.resolve_grad_path("pallas")
 
 
 # ---------------------------------------------------------------------------
-# reference stability: grad_path="reference" IS the pre-fusion math
+# dispatcher stability: the jnp expressions ARE the two-pass sums
 # ---------------------------------------------------------------------------
 
 def test_reference_round_gradient_is_pre_fusion_expression():
